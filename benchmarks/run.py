@@ -488,7 +488,9 @@ def main(save_dispatch_table: bool = False) -> None:
         table2_timing,
         table3_factors,
     )
+    from repro.api.cache import enable_persistent_cache
 
+    enable_persistent_cache()
     print("name,us_per_call,derived")
     fig2_vectorfield.run()
     _, per_step = table2_timing.run()

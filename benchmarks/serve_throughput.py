@@ -65,7 +65,7 @@ width cannot change the winner.
 
 plus a COMPILE section (`bench_compile`): cold vs warm engine spin-up
 through the process-wide PlanCache, pure-AOT `lower().compile()` seconds,
-and a two-subprocess probe of the JAX persistent compilation cache
+and an in-process probe of the JAX persistent compilation cache
 (cross-restart cold-start) — `BENCH_serve.json["compile"]`, refreshable
 alone via `--compile-only`.
 
@@ -81,6 +81,7 @@ can track the serving-perf trajectory. `kernels.dispatch_table
 from __future__ import annotations
 
 import json
+import os
 import time
 
 import jax
@@ -730,33 +731,6 @@ def fleet_smoke(replicas: int = 2, min_ratio: float = 1.5, print_fn=print) -> bo
     return ok
 
 
-def _compile_probe_child(conn, n, e, k, hold_steps, cache_dir):
-    """Spawn target for the persistent-cache columns: build + warm ONE
-    engine config in a fresh process and report wall seconds. With both
-    probes pointed at the same `cache_dir`, the first populates the JAX
-    persistent compilation cache and the second reads its XLA executables
-    off disk — the cross-restart cold-start the ExecPlan flag buys."""
-    try:
-        import time as _time
-
-        import jax.numpy as _jnp
-
-        from repro.api import ExecPlan, compile_plan, make_spec
-
-        t0 = _time.perf_counter()
-        spec = make_spec(n=n, n_in=1, hold_steps=hold_steps, dtype=_jnp.float32)
-        sim = compile_plan(
-            spec,
-            ExecPlan(
-                ensemble=e, chunk_ticks=k, compilation_cache_dir=cache_dir
-            ),
-        )
-        sim.warmup()
-        conn.send(("ok", _time.perf_counter() - t0))
-    except Exception as exc:  # noqa: BLE001 — report, don't hang the parent
-        conn.send(("err", f"{type(exc).__name__}: {exc}"))
-
-
 def bench_compile(quick: bool = False, print_fn=print) -> dict:
     """Compile-path columns: what the PlanCache and the persistent disk
     cache each buy, in seconds, on this host.
@@ -769,16 +743,20 @@ def bench_compile(quick: bool = False, print_fn=print) -> dict:
       aot_s             lower().compile() of a second structural variant:
                         pure ahead-of-time compile seconds, no execution
       persistent_cold_s / persistent_warm_s / persistent_speedup
-                        two spawned subprocesses against one shared
-                        on-disk JAX compilation cache: the first pays the
-                        compile and populates disk, the second reads it
-                        back — process-restart cold-start. None when the
-                        persistent cache is unavailable on this jaxlib.
+                        one process against the on-disk JAX compilation
+                        cache (api/cache.resolve_cache_dir): AOT-compile a
+                        third structural variant, drop JAX's in-memory
+                        caches (jax.clear_caches), compile it again — the
+                        second compile reads the executable back off disk,
+                        the cold-start a restarted process pays.
+                        persistent_cold_new_entries counts the files the
+                        first compile wrote: 0 means an earlier run already
+                        cached it and the cold column was warm too.
+    Runs in one process: a child started after this process has touched a
+    TPU could not reach the chip.
     """
-    import multiprocessing as mp
-    import tempfile
-
     from repro.api import PLAN_CACHE, ExecPlan, compile_plan, make_spec
+    from repro.api.cache import enable_persistent_cache, persistent_cache_dir
 
     # Deliberately off-grid N: the tick workers are module-level jit
     # functions, so any (shape, statics) signature another section already
@@ -809,29 +787,25 @@ def bench_compile(quick: bool = False, print_fn=print) -> dict:
     compile_plan(spec_aot, plan).aot_compile()
     aot_s = time.perf_counter() - t0
 
-    persistent_cold_s = persistent_warm_s = None
-    try:
-        ctx = mp.get_context("spawn")
-        with tempfile.TemporaryDirectory(prefix="jaxcache-") as cache_dir:
-            times = []
-            for _ in range(2):
-                parent, child = ctx.Pipe()
-                proc = ctx.Process(
-                    target=_compile_probe_child,
-                    args=(child, n, e, CHUNK_TICKS, HOLD_STEPS, cache_dir),
-                    daemon=True,
-                )
-                proc.start()
-                child.close()
-                status, payload = parent.recv()
-                proc.join(timeout=60)
-                parent.close()
-                if status != "ok":
-                    raise RuntimeError(payload)
-                times.append(payload)
-            persistent_cold_s, persistent_warm_s = times
-    except Exception as exc:  # noqa: BLE001 — column is best-effort
-        print_fn(f"compile bench: persistent-cache probe skipped ({exc})")
+    if not enable_persistent_cache():
+        raise RuntimeError(
+            "persistent compilation cache is pinned to another directory "
+            f"({persistent_cache_dir()!r}); the persistent columns need "
+            "the resolved one"
+        )
+    cache_dir = persistent_cache_dir()
+    spec_p = make_spec(
+        n=n, n_in=1, hold_steps=HOLD_STEPS + 4, seed=91_003, dtype=jnp.float32
+    )
+    entries0 = len(os.listdir(cache_dir))
+    t0 = time.perf_counter()
+    compile_plan(spec_p, plan).aot_compile()
+    persistent_cold_s = time.perf_counter() - t0
+    new_entries = len(os.listdir(cache_dir)) - entries0
+    jax.clear_caches()
+    t0 = time.perf_counter()
+    compile_plan(spec_p, plan).aot_compile()
+    persistent_warm_s = time.perf_counter() - t0
 
     out = {
         "n": n,
@@ -845,11 +819,9 @@ def bench_compile(quick: bool = False, print_fn=print) -> dict:
         "aot_s": aot_s,
         "persistent_cold_s": persistent_cold_s,
         "persistent_warm_s": persistent_warm_s,
-        "persistent_speedup": (
-            persistent_cold_s / max(persistent_warm_s, 1e-9)
-            if persistent_cold_s is not None
-            else None
-        ),
+        "persistent_speedup": persistent_cold_s / max(persistent_warm_s, 1e-9),
+        "persistent_cold_new_entries": new_entries,
+        "persistent_cache_dir": cache_dir,
         "cache_stats": PLAN_CACHE.stats.snapshot(),
     }
     print_fn(
@@ -859,14 +831,13 @@ def bench_compile(quick: bool = False, print_fn=print) -> dict:
             f"warm_{out['warm_speedup']:.0f}x_aot_{aot_s:.2f}s",
         )
     )
-    if persistent_cold_s is not None:
-        print_fn(
-            csv_row(
-                "serve_compile_persistent",
-                persistent_warm_s * 1e6,
-                f"vs_cold_{out['persistent_speedup']:.2f}x",
-            )
+    print_fn(
+        csv_row(
+            "serve_compile_persistent",
+            persistent_warm_s * 1e6,
+            f"vs_cold_{out['persistent_speedup']:.2f}x_new_{new_entries}",
         )
+    )
     return out
 
 
@@ -951,6 +922,9 @@ if __name__ == "__main__":
                     help="CI gate: 2-replica bursty mixed-N smoke through "
                          "the async front-end; exits nonzero on failure")
     args = ap.parse_args()
+    from repro.api.cache import enable_persistent_cache
+
+    enable_persistent_cache()
     if args.fleet_smoke:
         raise SystemExit(0 if fleet_smoke(replicas=args.replicas) else 1)
     elif args.fleet_only:

@@ -39,9 +39,10 @@ compiling that bucket WAITS for that one compile instead of duplicating it
 — and compiles of other keys proceed concurrently.
 
 The JAX persistent compilation cache rides along (`enable_persistent_cache`
-/ `ExecPlan.compilation_cache_dir`): with a cache dir configured, the XLA
-executables the workers compile are spilled to disk, so cold-start survives
-process restarts (measured in BENCH_serve.json["compile"]).
+/ `ExecPlan.compilation_cache_dir`): once enabled, the XLA executables the
+workers compile are spilled to disk, so cold-start survives process
+restarts. `resolve_cache_dir` decides where: JAX_COMPILATION_CACHE_DIR when
+set, else the requested directory, else DEFAULT_CACHE_DIR in the checkout.
 """
 
 from __future__ import annotations
@@ -49,6 +50,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import os
+import pathlib
 import threading
 import time
 import warnings
@@ -68,6 +70,7 @@ __all__ = [
     "PLAN_CACHE",
     "enable_persistent_cache",
     "plan_cache_key",
+    "resolve_cache_dir",
     "spec_structural_hash",
 ]
 
@@ -195,18 +198,55 @@ def _params_equal(a, b) -> bool:
 
 _PERSISTENT_LOCK = threading.Lock()
 _PERSISTENT_DIR: Optional[str] = None
+_WARNED_OVERRIDE = False
+
+#: Where the cache lives when JAX_COMPILATION_CACHE_DIR is unset: a fixed
+#: path inside the checkout (gitignored). JAX keys entries by path among
+#: other things, so a directory that moves between runs never hits.
+DEFAULT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
 
 
-def enable_persistent_cache(directory: str) -> bool:
-    """Point JAX's persistent compilation cache at `directory` (idempotent).
+def resolve_cache_dir(requested: Optional[str] = None) -> str:
+    """The one place that decides where compiled executables persist.
 
-    First configured directory wins for the process — JAX reads the config
-    at compile time and re-pointing mid-flight would split the cache; a
-    later call with a different directory warns and is ignored. Returns
-    True when the cache is (now) active for `directory`.
+    JAX_COMPILATION_CACHE_DIR, when set, wins over everything: JAX reads it
+    itself and no code here points the cache elsewhere (a `requested`
+    directory that differs is ignored, with one warning per process).
+    Otherwise `requested` (ExecPlan.compilation_cache_dir, the launcher's
+    --compilation-cache-dir), else DEFAULT_CACHE_DIR.
+    """
+    global _WARNED_OVERRIDE
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        if (
+            requested
+            and os.path.abspath(requested) != os.path.abspath(env)
+            and not _WARNED_OVERRIDE
+        ):
+            _WARNED_OVERRIDE = True
+            warnings.warn(
+                f"JAX_COMPILATION_CACHE_DIR={env!r} is set; ignoring the "
+                f"requested compilation cache directory {requested!r}",
+                RuntimeWarning,
+                stacklevel=3,
+            )
+        return env
+    return str(requested) if requested else DEFAULT_CACHE_DIR
+
+
+def enable_persistent_cache(directory: Optional[str] = None) -> bool:
+    """Turn on JAX's persistent compilation cache (idempotent).
+
+    The directory comes from `resolve_cache_dir(directory)`. First resolved
+    directory wins for the process — JAX reads the config at compile time
+    and re-pointing mid-flight would split the cache; a later call that
+    resolves elsewhere warns and is ignored. Returns True when the cache
+    is (now) active in the resolved directory.
     """
     global _PERSISTENT_DIR
-    directory = str(directory)
+    from jax.experimental.compilation_cache import compilation_cache
+
+    directory = resolve_cache_dir(directory)
     with _PERSISTENT_LOCK:
         if _PERSISTENT_DIR is not None:
             if _PERSISTENT_DIR != directory:
@@ -219,36 +259,18 @@ def enable_persistent_cache(directory: str) -> bool:
                 )
                 return False
             return True
-        try:
-            os.makedirs(directory, exist_ok=True)
+        os.makedirs(directory, exist_ok=True)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             jax.config.update("jax_compilation_cache_dir", directory)
-        except Exception as exc:  # pragma: no cover - jax version gate
-            warnings.warn(
-                f"JAX persistent compilation cache unavailable: {exc!r}",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            return False
         # cache every executable, however small/fast the compile — this
         # stack's hot paths are many medium-sized modules, not one giant one
-        for knob, value in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ):
-            try:
-                jax.config.update(knob, value)
-            except Exception:  # pragma: no cover - older jax
-                pass
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         # JAX initializes its disk cache lazily at the FIRST compile and
         # never re-reads the config: any compile before this call (spec
         # construction, dispatch probing) would freeze it disabled. Reset
         # so the next compile re-checks jax_compilation_cache_dir.
-        try:
-            from jax._src.compilation_cache import reset_cache
-
-            reset_cache()
-        except Exception:  # pragma: no cover - private API moved
-            pass
+        compilation_cache.reset_cache()
         _PERSISTENT_DIR = directory
         return True
 

@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import integrators, sto
-from repro.core.constants import STOParams
+from repro.core.constants import EXACT_MATMUL, STOParams
 from repro.kernels import ops
 from repro.kernels import ref as kref
 from repro.kernels import rls as krls
@@ -90,7 +90,9 @@ def _drive_scan(
     def per_sample(m, u_t):
         # Input held piecewise-constant over the hold window (paper: the
         # input signal is a discrete-point series).
-        h_in_x = params.a_in * (w_in @ u_t)  # (N,)
+        h_in_x = params.a_in * jnp.matmul(
+            w_in, u_t, precision=EXACT_MATMUL
+        )  # (N,)
 
         def inner(mi, _):
             return step(mi, dt, h_in_x), None
@@ -123,7 +125,9 @@ def _drive_scan_batch(
     dt = jnp.asarray(dt, dtype=m0_e.dtype)
 
     def per_sample(m, u_t):
-        h_in = params_e.a_in * jnp.einsum("ni,ei->en", w_in, u_t)  # (E, N)
+        h_in = params_e.a_in * jnp.einsum(
+            "ni,ei->en", w_in, u_t, precision=EXACT_MATMUL
+        )  # (E, N)
 
         def inner(mi, _):
             return step(mi, dt, h_in), None
@@ -147,7 +151,9 @@ def _tick_scan(params_e, w_cp, w_in, m_planes, u, mask, dt, hold_steps,
     drive() results; masked (idle) lanes return unchanged.
     """
     m = jnp.transpose(m_planes, (2, 1, 0))  # (E, N, 3)
-    h_in = params_e.a_in * jnp.einsum("ni,ei->en", w_in, u)  # (E, N)
+    h_in = params_e.a_in * jnp.einsum(
+        "ni,ei->en", w_in, u, precision=EXACT_MATMUL
+    )  # (E, N)
 
     def field(mm, h):
         return sto.llg_field(mm, params_e, w_cp, h)
@@ -184,7 +190,9 @@ def _tick_chunk_scan(params_e, w_cp, w_in, m_planes, u_block, mask_block, dt,
 
     def per_tick(m_c, tick_in):
         u_t, mask_t = tick_in
-        h_in = params_e.a_in * jnp.einsum("ni,ei->en", w_in, u_t)  # (E, N)
+        h_in = params_e.a_in * jnp.einsum(
+            "ni,ei->en", w_in, u_t, precision=EXACT_MATMUL
+        )  # (E, N)
 
         def inner(mi, _):
             return step(mi, dt, h_in), None
@@ -519,8 +527,12 @@ def _tick_chunk_scan_family(
         def per_tick(m_c, tick_in):
             u_t, mask_t = tick_in
             x_prev = m_c[..., 0]  # (E, N) previous tick's snapshots
-            h = params_e.a_in * jnp.einsum("ni,ei->en", w_in, u_t)
-            h = h + params_e.a_cp * jnp.einsum("nj,ej->en", w_cp, x_prev)
+            h = params_e.a_in * jnp.einsum(
+                "ni,ei->en", w_in, u_t, precision=EXACT_MATMUL
+            )
+            h = h + params_e.a_cp * jnp.einsum(
+                "nj,ej->en", w_cp, x_prev, precision=EXACT_MATMUL
+            )
             s0 = m_c[:, -1:, :]  # carried oscillator state (E, 1, 3)
 
             def per_node(s, h_col):  # h_col (E,) — this node's drive
@@ -547,7 +559,9 @@ def _tick_chunk_scan_family(
 
     def per_tick(m_c, tick_in):
         u_t, mask_t = tick_in
-        h_in = params_e.a_in * jnp.einsum("ni,ei->en", w_in, u_t)  # (E, N)
+        h_in = params_e.a_in * jnp.einsum(
+            "ni,ei->en", w_in, u_t, precision=EXACT_MATMUL
+        )  # (E, N)
 
         def inner(mi, _):
             return step(mi, dt, h_in), None
@@ -1403,6 +1417,50 @@ class CompiledSim:
 # ---------------------------------------------------------------------------
 
 
+def _kernel_shape(spec: SimSpec, plan: ExecPlan) -> dict:
+    """The plan's kernel-shaping knobs, as the VMEM fit check takes them."""
+    return dict(
+        k_ticks=max(plan.chunk_ticks, 1), hold_steps=spec.hold_steps,
+        n_inner=plan.n_inner or spec.hold_steps,
+        block_n=plan.block_n or ops.LANE, block_e=plan.block_e or ops.LANE,
+    )
+
+
+def _check_kernel_fits(spec: SimSpec, plan: ExecPlan, impl: str) -> None:
+    """Refuse, here, a Pallas impl the TPU compiler would refuse for VMEM.
+
+    Checks every kernel variant the plan dispatches: the chunk kernel runs
+    K ticks in tick_chunk and one in the per-window entry points; the
+    array_transient family also steps the fused kernel one step at a time.
+    The family chunk bodies are XLA, not the Pallas chunk kernel.
+    """
+    shape = _kernel_shape(spec, plan)
+    k_ticks = shape.pop("k_ticks")
+    hold, n_inner = shape.pop("hold_steps"), shape.pop("n_inner")
+    if impl == "chunk":
+        if spec.topology != "coupled_array":
+            return
+        variants = {(k_ticks, hold), (1, hold)}
+    else:
+        variants = {(1, ops.effective_n_inner(hold, n_inner))}
+        if spec.topology == "array_transient":
+            variants.add((1, 1))
+    for k, inner in sorted(variants):
+        refusal = ops.kernel_vmem_refusal(
+            impl, spec.n, plan.ensemble, itemsize=spec.dtype.itemsize,
+            precision=plan.effective_precision, k_ticks=k, n_inner=inner,
+            **shape,
+        )
+        if refusal is not None:
+            raise ValueError(
+                f"impl={impl!r} cannot hold N={spec.n}, E={plan.ensemble} "
+                f"(chunk_ticks={k}, inner steps={inner}, precision="
+                f"{ops.normalize_precision(plan.precision)!r}) in the TPU's "
+                f"scoped VMEM limit of {ops.sto_step.VMEM_LIMIT_BYTES} bytes; "
+                f"use impl='auto' or another impl. Compiler: {refusal}"
+            )
+
+
 def compile_plan(spec: SimSpec, plan: Optional[ExecPlan] = None, **overrides) -> CompiledSim:
     """Bind a SimSpec to an ExecPlan, resolving every execution decision.
 
@@ -1473,6 +1531,7 @@ def compile_plan(spec: SimSpec, plan: Optional[ExecPlan] = None, **overrides) ->
             impl = ops.choose_impl(
                 spec.n, plan.ensemble, spec.dtype.itemsize,
                 precision=plan.effective_precision,
+                **_kernel_shape(spec, plan),
             )
             if impl in ("fused", "tiled", "chunk") and spec.tableau != "rk4":
                 # the table's winner was measured on RK4 workloads; an
@@ -1491,6 +1550,8 @@ def compile_plan(spec: SimSpec, plan: Optional[ExecPlan] = None, **overrides) ->
             f"the fused kernels integrate classical RK4 only; impl={impl!r} "
             f"cannot run tableau {spec.tableau!r} (use impl='scan' or 'ref')"
         )
+    if impl in ("fused", "tiled", "chunk") and not plan.interpret:
+        _check_kernel_fits(spec, plan, impl)
     sim = CompiledSim(spec, plan, impl)
     if plan.aot:
         try:

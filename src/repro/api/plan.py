@@ -21,7 +21,9 @@ Every execution decision that used to be scattered across
   n_inner    fused-kernel inner steps (None = one hold window per launch).
   mesh       a jax Mesh makes the plan SHARDED: E spans `ensemble_axes`,
              N spans `model_axis`, with PartitionSpecs from
-             `distributed.sharding.reservoir_specs`.
+             `distributed.sharding.reservoir_specs`. The sharded bodies
+             run on its devices and axis names with Auto axis types
+             (`distributed.sharding.auto_axes`), whatever types it has.
   precision  numerical policy for the compute-bound GEMMs (the paper's
              large-N regime is dominated by the dense N x N coupling GEMM
              re-evaluated 4 x hold_steps times per tick):
@@ -70,12 +72,15 @@ Every execution decision that used to be scattered across
              not wired, e.g. sharded plans) instead of deferring XLA work
              to the first dispatch. Pair with `compilation_cache_dir` to
              populate the on-disk cache at spin-up.
-  compilation_cache_dir  opt into JAX's persistent compilation cache: the
-             XLA executables this plan compiles are spilled to (and read
-             back from) this directory, so cold-start survives process
-             restarts. First configured directory wins for the process
-             (api/cache.enable_persistent_cache); launcher flag
-             `--compilation-cache-dir` threads it through serve + fleet.
+  compilation_cache_dir  turn on JAX's persistent compilation cache for
+             the process: the XLA executables this plan compiles are
+             spilled to (and read back from) disk, so cold-start survives
+             process restarts. The directory is resolved by
+             api/cache.resolve_cache_dir: JAX_COMPILATION_CACHE_DIR when
+             set (this field is then ignored, with a warning if it
+             differs), else this field. First resolved directory wins for
+             the process; launcher flag `--compilation-cache-dir` threads
+             it through serve + fleet.
              Neither field changes numerics or the compiled executable —
              both are excluded from the PlanCache key.
   learn_lam  RLS forgetting factor in (0, 1]. 1.0 (default) weights all
@@ -96,10 +101,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
-try:  # jax is a hard dependency of the repo; guard only for doc tooling
-    from jax.sharding import Mesh
-except Exception:  # pragma: no cover
-    Mesh = object  # type: ignore
+from jax.sharding import Mesh
 
 PLAN_IMPLS = ("auto", "scan", "ref", "fused", "tiled", "chunk")
 PLAN_LEARN = (None, "rls", "lms")

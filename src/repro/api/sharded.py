@@ -36,8 +36,8 @@ from jax.sharding import Mesh
 from repro.core.compat import SHARD_MAP_CHECK_KW as _SHARD_MAP_CHECK_KW
 from repro.core.compat import shard_map
 from repro.core import integrators, sto
-from repro.core.constants import STOParams
-from repro.distributed.sharding import reservoir_specs
+from repro.core.constants import EXACT_MATMUL, STOParams
+from repro.distributed.sharding import auto_axes, reservoir_specs
 from repro.kernels import rls as krls
 
 
@@ -64,7 +64,8 @@ def _coupling_field(params_l, w_mm, m, model_axis, gather_dtype):
     else:
         mx_full = mx
     return params_l.a_cp * jnp.einsum(
-        "ki,...i->...k", w_mm, mx_full, preferred_element_type=m.dtype
+        "ki,...i->...k", w_mm, mx_full, preferred_element_type=m.dtype,
+        precision=EXACT_MATMUL,
     )
 
 
@@ -82,6 +83,7 @@ def integrate_sharded(
     precision=None,  # free-run has no input GEMM; coupling rides gather_dtype
 ):
     """Free-running (u = 0) sharded ensemble integration -> final (E, N, 3)."""
+    mesh = auto_axes(mesh)
     tableau = integrators.TABLEAUX[tableau_name]
     specs = reservoir_specs(ensemble_axes, model_axis)
 
@@ -133,6 +135,7 @@ def drive_sharded(
     only on the LOCAL N rows, so the input path adds no collectives; only
     the coupling gathers.
     """
+    mesh = auto_axes(mesh)
     tableau = integrators.TABLEAUX[tableau_name]
     specs = reservoir_specs(ensemble_axes, model_axis)
     per_lane_u = u_seq.ndim == 3
@@ -426,6 +429,7 @@ def tick_chunk_sharded_rls(
     model axis. Returns (m' (E, N, 3), states (K, E, N), P', W',
     preds (K, E, n_out)).
     """
+    mesh = auto_axes(mesh)
     fn = _tick_chunk_sharded_rls_fn(
         mesh, tuple(ensemble_axes), model_axis, tableau_name,
         float(dt), int(hold_steps), gather_dtype, float(lam), precision,
@@ -457,6 +461,7 @@ def tick_chunk_sharded(
     (K, E, N) states block stays on device until the engine's bulk harvest.
     Returns (m' (E, N, 3), states (K, E, N)).
     """
+    mesh = auto_axes(mesh)
     fn = _tick_chunk_sharded_fn(
         mesh, tuple(ensemble_axes), model_axis, tableau_name,
         float(dt), int(hold_steps), gather_dtype, precision,
@@ -487,6 +492,7 @@ def tick_sharded(
     back bit-identical so idle serving slots stay frozen. Returns
     (m' (E, N, 3), states (E, N)).
     """
+    mesh = auto_axes(mesh)
     fn = _tick_sharded_fn(
         mesh, tuple(ensemble_axes), model_axis, tableau_name,
         float(dt), int(hold_steps), gather_dtype, precision,

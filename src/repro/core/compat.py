@@ -1,18 +1,11 @@
-"""jax version compatibility shims (single source of truth).
+"""shard_map and its replication-check switch (single source of truth).
 
-shard_map: jax >= 0.6 exports it at the top level and spells the
-replication-check kwarg `check_vma`; older jax ships it under
-jax.experimental with `check_rep`. Callers do:
+Callers do:
 
     from repro.core.compat import shard_map, SHARD_MAP_CHECK_KW
     shard_map(f, mesh=..., in_specs=..., out_specs=..., **SHARD_MAP_CHECK_KW)
 """
 
-try:
-    from jax import shard_map  # noqa: F401
+from jax import shard_map  # noqa: F401
 
-    SHARD_MAP_CHECK_KW = {"check_vma": False}
-except ImportError:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-    SHARD_MAP_CHECK_KW = {"check_rep": False}
+SHARD_MAP_CHECK_KW = {"check_vma": False}

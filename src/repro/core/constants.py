@@ -18,7 +18,15 @@ from __future__ import annotations
 import math
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
+
+# Precision of every f32 matmul on the reservoir's path. On a TPU, XLA's
+# default for an f32 dot is a single bf16 pass; HIGHEST keeps f32 operands
+# f32 there, and changes nothing on the CPU. Reduced-precision policies
+# (ExecPlan.precision) cast their operands to bf16 first, so they stay one
+# bf16 pass.
+EXACT_MATMUL = jax.lax.Precision.HIGHEST
 
 # Fundamental constants (paper Table 1).
 HBAR = 1.05457266e-34  # J s

@@ -15,6 +15,8 @@ from __future__ import annotations
 import numpy as np
 import jax.numpy as jnp
 
+from repro.core.constants import EXACT_MATMUL
+
 # Above this N, exact eigvals (O(N^3)) get replaced by the circular-law
 # estimate with a power-iteration refinement.
 _EXACT_EIG_MAX_N = 2048
@@ -86,4 +88,6 @@ def coupling_field_x(w_cp: jnp.ndarray, mx: jnp.ndarray, a_cp) -> jnp.ndarray:
     mx: (..., N) -> returns (..., N). Batched as a matmul over trailing axis,
     which maps onto the MXU when the batch (ensemble) axis is >= 128.
     """
-    return a_cp * jnp.einsum("ki,...i->...k", w_cp, mx)
+    return a_cp * jnp.einsum(
+        "ki,...i->...k", w_cp, mx, precision=EXACT_MATMUL
+    )

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
-from repro.core.constants import STOParams
+from repro.core.constants import EXACT_MATMUL, STOParams
 
 
 def broadcast_params(base: STOParams, size: int, **sweeps) -> STOParams:
@@ -177,8 +177,8 @@ def fit_ridge_ensemble(states: jnp.ndarray, targets: jnp.ndarray, reg: float = 1
     def fit_one(xe):  # (T', N)
         ones = jnp.ones((xe.shape[0], 1), xe.dtype)
         xb = jnp.concatenate([xe, ones], axis=1)
-        gram = xb.T @ xb
-        rhs = xb.T @ y.astype(xe.dtype)
+        gram = jnp.matmul(xb.T, xb, precision=EXACT_MATMUL)
+        rhs = jnp.matmul(xb.T, y.astype(xe.dtype), precision=EXACT_MATMUL)
         return jnp.linalg.solve(
             gram + reg * jnp.eye(gram.shape[0], dtype=gram.dtype), rhs
         )

@@ -148,8 +148,8 @@ def fit_ridge(
     x = x.astype(y.dtype)
     ones = jnp.ones((x.shape[0], 1), dtype=x.dtype)
     xb = jnp.concatenate([x, ones], axis=1)  # (T', N+1)
-    gram = xb.T @ xb
-    rhs = xb.T @ y
+    gram = jnp.matmul(xb.T, xb, precision=constants.EXACT_MATMUL)
+    rhs = jnp.matmul(xb.T, y, precision=constants.EXACT_MATMUL)
     w = jnp.linalg.solve(gram + reg * jnp.eye(gram.shape[0], dtype=gram.dtype), rhs)
     return Readout(w_out=w, washout=washout)
 
@@ -320,7 +320,7 @@ def predict(readout: Readout, states: jnp.ndarray) -> jnp.ndarray:
     x = states[readout.washout :]
     ones = jnp.ones((x.shape[0], 1), dtype=x.dtype)
     xb = jnp.concatenate([x, ones], axis=1).astype(readout.w_out.dtype)
-    return xb @ readout.w_out
+    return jnp.matmul(xb, readout.w_out, precision=constants.EXACT_MATMUL)
 
 
 def nmse(pred: jnp.ndarray, target: jnp.ndarray) -> float:

@@ -25,7 +25,22 @@ from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis typed Auto.
+
+    `jax.make_mesh` types its axes Explicit, under which an array's sharding
+    is part of its type: indexing a sharded array then needs an out_sharding
+    and with_sharding_constraint refuses the spec. The rules in this module
+    and the reservoir's shard_map bodies place data through NamedShardings
+    and leave propagation to the compiler, which is what Auto axes mean.
+    """
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 # ---------------------------------------------------------------------------

@@ -46,12 +46,10 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.constants import STOParams
+from repro.core.constants import EXACT_MATMUL, STOParams
 from repro.kernels import ref as kref
 from repro.kernels import sto_step
 
-# VMEM budget used by auto-dispatch (bytes); v5e has ~16 MiB per core.
-VMEM_BUDGET = 12 * 1024 * 1024
 LANE = sto_step.LANE
 
 
@@ -59,10 +57,96 @@ def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
 
 
-def fused_fits_vmem(n: int, block_e: int, itemsize: int = 4) -> bool:
-    """W (n^2) + ~8 live (n, block_e) planes per fused step must fit VMEM."""
-    need = n * n * itemsize + 8 * n * block_e * itemsize
-    return need <= VMEM_BUDGET
+def _vmem_refusal_line(exc: Exception) -> Optional[str]:
+    """The compiler's VMEM out-of-memory line in `exc`, if it is one."""
+    msg = str(exc)
+    if "RESOURCE_EXHAUSTED" not in msg:
+        return None
+    lines = [ln.strip() for ln in msg.splitlines() if "vmem" in ln.lower()]
+    return lines[0][:400] if lines else None
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_refusal(impl, n, e, w_dtype, dtype, k_ticks, n_inner,
+                    block_n, block_e, device) -> Optional[str]:
+    """Compile the pallas_call `impl` runs at padded (n, e) for `device`."""
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(device)
+
+    def s(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    dt = 1.0e-11  # a compile-time constant of the kernel; any value sizes it
+    planes, w, pv, h = s((3, n, e)), s((n, n), w_dtype), s((kref.NP, e)), s((n, e))
+    if impl == "fused":
+        fn = lambda m, w, p, h: sto_step.rk4_fused(
+            m, w, p, dt, n_inner=n_inner, block_e=block_e, h_in=h)
+        args = (planes, w, pv, h)
+    elif impl == "tiled":
+        fn = lambda m, y, k, w, p, h: sto_step.field_tiled(
+            m, y, k, w, p, 0.5 * dt, block_n=block_n, block_e=block_e, h_in=h)
+        args = (planes, h, planes, w, pv, h)
+    elif impl == "chunk":
+        fn = lambda m, w, p, hb, mb: sto_step.rk4_chunk(
+            m, w, p, dt, n_inner, hb, mb, block_e=block_e)
+        args = (planes, w, pv, s((k_ticks, n, e)), s((k_ticks, e)))
+    else:
+        raise ValueError(f"{impl!r} is not a Pallas impl")
+    try:
+        jax.jit(fn).lower(*args).compile()
+    except Exception as exc:  # only a VMEM refusal means "does not fit"
+        refusal = _vmem_refusal_line(exc)
+        if refusal is None:
+            raise
+        return refusal
+    return None
+
+
+def kernel_vmem_refusal(
+    impl: str,
+    n: int,
+    e: int,
+    *,
+    itemsize: int = 4,
+    precision: Optional[str] = None,
+    k_ticks: int = 1,
+    n_inner: int = 1,
+    block_n: int = LANE,
+    block_e: int = LANE,
+) -> Optional[str]:
+    """Why the TPU compiler refuses the `impl` kernel at (N, E), or None.
+
+    The fit check behind impl="auto" and compile_plan. The kernels' scoped
+    VMEM use depends on W, every double-buffered block, the stage
+    temporaries and on how Mosaic schedules the RK4 loop (straight-line
+    code for one inner step needs about twice what a loop of several
+    does), so no closed form tracks it: the check compiles the exact
+    pallas_call at the padded shape under sto_step.VMEM_LIMIT_BYTES and
+    reports the compiler's own refusal, for the first JAX device. Memoized
+    per shape and device. Off a TPU no Mosaic kernel is compiled and
+    nothing is refused. Compile errors other than a VMEM refusal propagate.
+    """
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return None
+    dtype = jnp.dtype(jnp.float32 if itemsize == 4 else jnp.float64)
+    w_dtype = _coupling_operand(
+        jnp.zeros((), dtype), normalize_precision(precision)
+    ).dtype
+    return _kernel_refusal(
+        impl, _round_up(n, block_n), _round_up(e, block_e), w_dtype, dtype,
+        int(k_ticks), int(n_inner), int(block_n), int(block_e), device,
+    )
+
+
+def effective_n_inner(n_steps: int, n_inner: int) -> int:
+    """The fused kernel's inner-step count: the largest divisor of n_steps
+    not above n_inner."""
+    n_inner = max(1, min(int(n_inner), int(n_steps)))
+    while n_steps % n_inner != 0:
+        n_inner -= 1
+    return n_inner
 
 
 # ---------------------------------------------------------------------------
@@ -134,6 +218,12 @@ def choose_impl(
     itemsize: int = 4,
     platform: Optional[str] = None,
     precision: Optional[str] = None,
+    *,
+    k_ticks: int = 1,
+    hold_steps: int = 8,
+    n_inner: int = 8,
+    block_n: int = LANE,
+    block_e: int = LANE,
 ) -> str:
     """Resolve impl="auto" for a given (N, E, precision) problem shape.
 
@@ -143,21 +233,39 @@ def choose_impl(
     same shape (the best f32 impl is the best prior for a reduced-precision
     run that was never measured) > platform gate (Pallas kernels only
     compile on TPU; everything else integrates through the jnp oracle,
-    which XLA fuses well on CPU/GPU) > VMEM-fit heuristic.
+    which XLA fuses well on CPU/GPU) > the VMEM fit check: fused, else
+    tiled, else the XLA oracle. On a TPU every Pallas choice, a table's
+    included, must pass `kernel_vmem_refusal` at the caller's chunk length
+    (k_ticks), hold window, fused inner-step count and blocks, so "auto"
+    never names a kernel the compiler refuses.
     """
     from repro.kernels import dispatch_table
 
     platform = platform or jax.default_backend()
     dispatch_table.ensure_loaded(platform)
     prec = normalize_precision(precision)
+
+    def fits(impl):
+        if platform != "tpu" or impl not in ("fused", "tiled", "chunk"):
+            return True
+        return kernel_vmem_refusal(
+            impl, n, e, itemsize=itemsize, precision=precision,
+            k_ticks=k_ticks,
+            n_inner=(hold_steps if impl == "chunk"
+                     else effective_n_inner(hold_steps, n_inner)),
+            block_n=block_n, block_e=block_e,
+        ) is None
+
     shape_key = (platform, _round_up(n, LANE), _round_up(e, LANE), itemsize)
-    if shape_key + (prec,) in _LATENCY_TABLE:
-        return _LATENCY_TABLE[shape_key + (prec,)]
-    if prec != PRECISION_DEFAULT and shape_key + (PRECISION_DEFAULT,) in _LATENCY_TABLE:
-        return _LATENCY_TABLE[shape_key + (PRECISION_DEFAULT,)]
+    for key in (shape_key + (prec,), shape_key + (PRECISION_DEFAULT,)):
+        if key in _LATENCY_TABLE and fits(_LATENCY_TABLE[key]):
+            return _LATENCY_TABLE[key]
     if platform != "tpu":
         return "ref"
-    return "fused" if fused_fits_vmem(_round_up(n, LANE), LANE, itemsize) else "tiled"
+    for impl in ("fused", "tiled"):
+        if fits(impl):
+            return impl
+    return "ref"
 
 
 def measure_impl_latency(
@@ -184,21 +292,20 @@ def measure_impl_latency(
     candidates there — timing two names for one computation would register
     a coin-flip winner; pass it via `candidates` explicitly if you must.
 
-    Returns {impl: seconds per chunk} for the candidates that ran, plus —
-    when any candidate failed — a "failed" entry mapping impl name to the
-    error string. Failures are also surfaced as a RuntimeWarning: a broken
-    backend must show up in the measurement report, not silently skew the
-    dispatch table toward whatever happened to survive. With register=True
-    the fastest surviving impl is written into the dispatch table so
-    subsequent impl="auto" calls at this padded (shape, precision) use the
-    measured choice.
+    Returns {impl: seconds per chunk} for the candidates that ran. On a
+    TPU, a Pallas candidate the VMEM fit check refuses at this shape is
+    left out up front and listed under "excluded" (impl -> the compiler's
+    refusal); an admitted candidate that then fails raises, because a
+    kernel the chip should run and does not is a fault, not a slower
+    choice. Off a TPU a failing candidate is listed under "failed" and
+    raised as a RuntimeWarning instead (fused/tiled need the TPU compiler).
+    With register=True the fastest candidate that ran is written into the
+    dispatch table so subsequent impl="auto" calls at this padded (shape,
+    precision) use the measured choice.
     """
+    on_tpu = jax.default_backend() == "tpu"
     if candidates is None:
-        candidates = (
-            ("fused", "tiled", "chunk", "ref")
-            if jax.default_backend() == "tpu"
-            else ("ref",)
-        )
+        candidates = ("fused", "tiled", "chunk", "ref") if on_tpu else ("ref",)
     from repro.core import constants, coupling
 
     w = jnp.asarray(coupling.make_coupling_matrix(n, seed=0), dtype)
@@ -210,21 +317,40 @@ def measure_impl_latency(
     mask_block = jnp.ones((chunk_ticks, e), dtype=bool)
     timings: Dict[str, object] = {}
     failed: Dict[str, str] = {}
+    excluded: Dict[str, str] = {}
+    itemsize = jnp.dtype(dtype).itemsize
     for impl in candidates:
+        if on_tpu and impl in ("fused", "tiled", "chunk"):
+            refusal = kernel_vmem_refusal(
+                impl, n, e, itemsize=itemsize, precision=precision,
+                k_ticks=chunk_ticks if impl == "chunk" else 1,
+                n_inner=n_steps if impl == "chunk" else effective_n_inner(n_steps, 8),
+            )
+            if refusal is not None:
+                excluded[impl] = refusal
+                continue
         fn = lambda: sto_rk4_tick_chunk_planes(
             m0, w, pv, float(dt), n_steps, h_block, mask_block,
             impl=impl, precision=precision,
         )[0]
         try:
             jax.block_until_ready(fn())  # compile + warm
-            times = []
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn())
-                times.append(time.perf_counter() - t0)
-            timings[impl] = sorted(times)[len(times) // 2]
-        except Exception as exc:  # impl unavailable on this backend/shape
+        except Exception as exc:
+            if on_tpu:
+                raise RuntimeError(
+                    f"measure_impl_latency({n}, {e}): impl {impl!r} passed "
+                    "the VMEM fit check but failed to run"
+                ) from exc
             failed[impl] = f"{type(exc).__name__}: {exc}"
+            continue
+        times = []
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn())
+            times.append(time.perf_counter() - t0)
+        timings[impl] = sorted(times)[len(times) // 2]
+    if excluded:
+        timings["excluded"] = excluded
     if failed:
         import warnings
 
@@ -317,7 +443,10 @@ def sto_rk4_integrate_planes(
     """
     _, n, e = m0.shape
     if impl == "auto":
-        impl = choose_impl(n, e, m0.dtype.itemsize, precision=precision)
+        impl = choose_impl(
+            n, e, m0.dtype.itemsize, precision=precision, hold_steps=n_steps,
+            n_inner=n_inner, block_n=block_n, block_e=block_e,
+        )
     return _integrate_planes_jit(
         m0, w_cp, params_vec, h_in, lane_mask,
         dt=dt, n_steps=n_steps, impl=impl, n_inner=n_inner,
@@ -342,7 +471,7 @@ def input_field_einsum(eq: str, w_in, u, precision) -> jnp.ndarray:
             eq, w_in.astype(jnp.bfloat16), u.astype(jnp.bfloat16),
             preferred_element_type=u.dtype,
         )
-    return jnp.einsum(eq, w_in, u)
+    return jnp.einsum(eq, w_in, u, precision=EXACT_MATMUL)
 
 
 def _coupling_operand(w: jnp.ndarray, precision: str) -> jnp.ndarray:
@@ -405,8 +534,7 @@ def _integrate_planes_jit(
 
         m, _ = jax.lax.scan(body, m, None, length=n_steps)
     elif impl == "fused":
-        while n_steps % n_inner != 0:
-            n_inner -= 1
+        n_inner = effective_n_inner(n_steps, n_inner)
 
         def body(mm, _):
             return (
@@ -468,7 +596,11 @@ def sto_rk4_tick_chunk_planes(
     """
     _, n, e = m0.shape
     if impl == "auto":
-        impl = choose_impl(n, e, m0.dtype.itemsize, precision=precision)
+        impl = choose_impl(
+            n, e, m0.dtype.itemsize, precision=precision,
+            k_ticks=h_block.shape[0], hold_steps=hold_steps, n_inner=n_inner,
+            block_n=block_n, block_e=block_e,
+        )
     return _tick_chunk_planes_jit(
         m0, w_cp, params_vec, h_block, mask_block,
         dt=dt, hold_steps=hold_steps, impl=impl, n_inner=n_inner,
@@ -520,8 +652,7 @@ def _tick_chunk_planes_jit(
         )
     elif impl in ("fused", "tiled"):
         if impl == "fused":
-            while hold_steps % n_inner != 0:
-                n_inner -= 1
+            n_inner = effective_n_inner(hold_steps, n_inner)
 
         def per_tick(mm, tick_in):
             h_t, mask_t = tick_in
@@ -581,7 +712,11 @@ def sto_rk4_integrate(
     for s in batch_shape:
         e *= int(s)
     if impl == "auto":
-        impl = choose_impl(m0.shape[-2], e, m0.dtype.itemsize, precision=precision)
+        impl = choose_impl(
+            m0.shape[-2], e, m0.dtype.itemsize, precision=precision,
+            hold_steps=n_steps, n_inner=n_inner, block_n=block_n,
+            block_e=block_e,
+        )
     m = _integrate_planes_jit(
         to_planes(m0), w_cp, params_vec, None, None,
         dt=dt, n_steps=n_steps, impl=impl, n_inner=n_inner,

@@ -18,7 +18,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.constants import STOParams
+from repro.core.constants import EXACT_MATMUL, STOParams
 
 PARAM_LAYOUT: Tuple[str, ...] = (
     "pref",  # gamma / (1 + alpha^2)
@@ -88,7 +88,9 @@ def llg_field_planes(m, w_cp, pvec, h_in=None):
     mx, my, mz = m[0], m[1], m[2]  # (N, E)
     # coupling: rows of W against the x-plane -> (N, E) matmul on the MXU
     mx_cp = mx if w_cp.dtype == m.dtype else mx.astype(w_cp.dtype)
-    hx = p["a_cp"] * jnp.dot(w_cp, mx_cp, preferred_element_type=m.dtype)
+    hx = p["a_cp"] * jnp.dot(
+        w_cp, mx_cp, preferred_element_type=m.dtype, precision=EXACT_MATMUL
+    )
     if h_in is not None:
         hx = hx + h_in
     hz = p["happl"] + p["demag"] * mz
@@ -259,7 +261,7 @@ def tm_chunk_planes(
         x_prev = mm[0]  # (N, E) previous tick's snapshots
         x_cp = x_prev if w_cp.dtype == mm.dtype else x_prev.astype(w_cp.dtype)
         h_t = h_ext_t + p["a_cp"] * jnp.dot(
-            w_cp, x_cp, preferred_element_type=mm.dtype
+            w_cp, x_cp, preferred_element_type=mm.dtype, precision=EXACT_MATMUL
         )  # (N, E)
         s0 = mm[:, n - 1 : n, :]  # carried oscillator state (3, 1, E)
 

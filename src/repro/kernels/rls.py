@@ -52,6 +52,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.constants import EXACT_MATMUL
+
 
 def rls_init(
     e: int, n_state: int, n_out: int, reg: float, dtype
@@ -171,7 +173,7 @@ def rls_chunk(
         b = jnp.sum(p * xb[0][:, None, :], axis=-1)[:, :, None]  # (E, S, 1)
     else:
         xk = jnp.transpose(xb, (1, 0, 2))  # (E, K, S)
-        b = jnp.einsum("eij,etj->eit", p, xk)  # (E, S, K)
+        b = jnp.einsum("eij,etj->eit", p, xk, precision=EXACT_MATMUL)  # (E, S, K)
 
     # gst / pxst grow one (E, 1, S) row per tick — batching each tick's
     # corrections against ALL prior pairs keeps the unrolled op count O(K)
@@ -216,7 +218,9 @@ def rls_chunk(
     if lam != 1.0:
         gst = coefs[:, :, None] * gst
     p_scaled = p if lam == 1.0 else cum[:, None, None] * p
-    p_new = p_scaled - jnp.einsum("eti,etj->eij", gst, pxst)
+    p_new = p_scaled - jnp.einsum(
+        "eti,etj->eij", gst, pxst, precision=EXACT_MATMUL
+    )
     return p_new, w_t, jnp.stack(preds)  # (E,S,S), (E,S,O), (K,E,O)
 
 

@@ -47,12 +47,33 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.constants import EXACT_MATMUL
 from repro.kernels.ref import NP
 
 # MXU/VREG-aligned tile sizes (f32).
 LANE = 128
 SUBLANE = 8
+
+# Scoped-VMEM limit every pallas_call here compiles under. ops.py's fit
+# checks size the kernels against this same number.
+VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
+
+def _coupling_dot(w, x, acc_t):
+    """W @ x on the MXU, accumulating in acc_t.
+
+    f32 operands ask for EXACT_MATMUL (see core/constants.py). Reduced-
+    precision operands (bf16 W) are one bf16 pass by construction, and
+    Mosaic refuses HIGHEST on them.
+    """
+    precision = EXACT_MATMUL if w.dtype == jnp.float32 else None
+    return jnp.dot(w, x, preferred_element_type=acc_t, precision=precision)
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _field_planes(mx, my, mz, hx, p):
@@ -103,7 +124,7 @@ def _rk4_fused_kernel(params_ref, w_ref, h_ref, m_ref, out_ref, *, dt, n_inner):
         # pre-cast (e.g. bf16); the dot consumes the reduced operands and
         # accumulates in the state dtype (MXU-native bf16 x bf16 -> f32)
         mx_cp = mx if w.dtype == m_ref.dtype else mx.astype(w.dtype)
-        hx = p["a_cp"] * jnp.dot(w, mx_cp, preferred_element_type=acc_t) + h_in
+        hx = p["a_cp"] * _coupling_dot(w, mx_cp, acc_t) + h_in
         return _field_planes(mx, my, mz, hx, p)
 
     def one_step(state):
@@ -156,6 +177,7 @@ def rk4_fused(
         out_specs=pl.BlockSpec((3, n, block_e), lambda i: (0, 0, i)),
         out_shape=jax.ShapeDtypeStruct(m.shape, m.dtype),
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(params, w_cp, h_in, m)
 
 
@@ -184,8 +206,7 @@ def _field_tiled_kernel(
     if w_ref.dtype != m_ref.dtype:
         yx = yx.astype(w_ref.dtype)
     hx = (
-        p["a_cp"] * jnp.dot(w_ref[...], yx, preferred_element_type=acc_t)
-        + h_ref[...]
+        p["a_cp"] * _coupling_dot(w_ref[...], yx, acc_t) + h_ref[...]
     )
     if stage_coef == 0.0:
         yx, yy, yz = m_ref[0], m_ref[1], m_ref[2]
@@ -231,6 +252,7 @@ def field_tiled(
         out_specs=pl.BlockSpec((3, block_n, block_e), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct(m.shape, m.dtype),
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(params, w_cp, h_in, yx_full, m, k_prev)
 
 
@@ -252,7 +274,7 @@ def _rk4_chunk_kernel(
 
     def field(mx, my, mz, h_in):
         mx_cp = mx if w.dtype == m_ref.dtype else mx.astype(w.dtype)
-        hx = p["a_cp"] * jnp.dot(w, mx_cp, preferred_element_type=acc_t) + h_in
+        hx = p["a_cp"] * _coupling_dot(w, mx_cp, acc_t) + h_in
         return _field_planes(mx, my, mz, hx, p)
 
     def one_step(state, h_in):
@@ -327,6 +349,7 @@ def rk4_chunk(
             jax.ShapeDtypeStruct((k_ticks, n, e), m.dtype),
         ],
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(params, w_cp, h_block, mask_block.reshape(k_ticks, 1, e), m)
 
 

@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 cell on the production meshes, print memory/cost analysis, and dump the
 artifacts launch/roofline.py consumes.
@@ -13,6 +9,16 @@ artifacts launch/roofline.py consumes.
 Results are cached as JSON under experiments/dryrun/ (one file per cell) so
 re-runs skip completed cells; --force recompiles.
 """
+
+import os
+
+# 512 virtual CPU devices for the production meshes, added to whatever
+# XLA_FLAGS already holds (a caller's own device count wins)
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512"
+    ).strip()
 
 import argparse
 import json

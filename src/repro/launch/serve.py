@@ -216,6 +216,7 @@ def main_fleet(args):
     import asyncio
     import os
 
+    import jax
     import numpy as np
 
     from repro.serve.fleet import (
@@ -229,13 +230,16 @@ def main_fleet(args):
     planner = None
     bench = args.bench or "BENCH_serve.json"
     if os.path.exists(bench):
-        planner = CapacityModel.from_bench(bench)
+        planner = CapacityModel.from_bench(bench).checked_for(
+            jax.default_backend()
+        )
+    if planner is not None:
         err = planner.prediction_error()
         print(
             f"planner: calibrated from {bench} "
             f"(fit err median {err['median']:.0%} max {err['max']:.0%})"
         )
-    else:
+    elif not os.path.exists(bench):
         print(f"planner: {bench} not found — admission control disabled")
 
     router = FleetRouter(
@@ -275,14 +279,16 @@ def main_fleet(args):
     async def serve():
         async with FleetFrontend(router) as fleet:
             t0 = time.time()
-            for u in streams:
+            sids = [
                 await fleet.submit_stream(args.n, u, collect_states=False)
+                for u in streams
+            ]
             results = await fleet.drain_results()
             dt = time.time() - t0
             stats = fleet.stats()[args.n]
-            return results, dt, stats, fleet.fault_stats()
+            return sids, results, dt, stats, fleet.fault_stats()
 
-    results, dt, stats, faults = asyncio.run(serve())
+    sids, results, dt, stats, faults = asyncio.run(serve())
     ticks = sum(s.session_ticks for s in stats)
     print(
         f"fleet: {args.replicas}x(N={args.n}, E={args.slots}) "
@@ -303,9 +309,12 @@ def main_fleet(args):
             "fault tolerance: "
             + ", ".join(f"{k}={v}" for k, v in sorted(faults.items()))
         )
+    return {sid: (u, results.get(sid)) for sid, u in zip(sids, streams)}
 
 
 def main(argv=None):
+    """Parse argv and serve. Fleet mode returns {sid: (input stream,
+    SessionResult or None if it never drained)}."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["lm", "reservoir"], default="lm")
     # lm mode
@@ -374,21 +383,26 @@ def main(argv=None):
                     help="BENCH_serve.json to calibrate the capacity planner "
                          "from (default: ./BENCH_serve.json if present)")
     ap.add_argument("--compilation-cache-dir", default=None,
-                    help="directory for JAX's persistent compilation cache: "
-                         "XLA executables round-trip through disk, so a "
-                         "restarted server (and every process replica "
-                         "pointed at the same directory) skips its "
-                         "cold-start compiles")
+                    help="directory for JAX's persistent compilation cache "
+                         "(default: .jax_cache/ in the checkout; "
+                         "JAX_COMPILATION_CACHE_DIR, when set, wins): XLA "
+                         "executables round-trip through disk, so a "
+                         "restarted server (and every process replica) "
+                         "skips its cold-start compiles")
     args = ap.parse_args(argv)
 
     if args.autotune_budget and not args.learn:
         ap.error("--autotune-budget requires --learn (probe fitness is the "
                  "on-device learner's nmse)")
     if args.mode == "reservoir":
+        from repro.api.cache import enable_persistent_cache, persistent_cache_dir
+
+        enable_persistent_cache(args.compilation_cache_dir)
+        # process replicas inherit the resolved directory
+        args.compilation_cache_dir = persistent_cache_dir()
         if args.fleet:
-            main_fleet(args)
-        else:
-            main_reservoir(args)
+            return main_fleet(args)
+        main_reservoir(args)
     elif args.fleet:
         ap.error("--fleet requires --mode reservoir")
     else:

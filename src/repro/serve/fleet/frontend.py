@@ -45,6 +45,7 @@ import math
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, Optional
 
+import jax
 import numpy as np
 
 from repro.serve.reservoir import SessionResult, StreamSession
@@ -109,7 +110,11 @@ class FleetFrontend:
                 f"rpc_backoff_s ({rpc_backoff_s!r})"
             )
         self.router = router
-        self.planner = planner if planner is not None else router.planner
+        planner = planner if planner is not None else router.planner
+        self.planner = (
+            None if planner is None
+            else planner.checked_for(jax.default_backend())
+        )
         self.admit_window_s = admit_window_s
         self.max_waiters = max_waiters
         self.idle_sleep_s = idle_sleep_s

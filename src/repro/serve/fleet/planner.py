@@ -298,6 +298,19 @@ class CapacityModel:
             t /= max(self.precision_speedup, 1e-30)
         return t / max(self.host_scale, 1e-30)
 
+    def checked_for(self, platform: str, print_fn=print) -> Optional["CapacityModel"]:
+        """This model when it was calibrated on `platform`, else None with
+        a printed line: timings from another platform must not price
+        admission on this one."""
+        if platform == self.platform:
+            return self
+        print_fn(
+            f"planner: calibrated on {self.platform!r}, serving on "
+            f"{platform!r} — admission pricing disabled (re-run the serve "
+            f"benchmark on {platform!r} to plan for it)"
+        )
+        return None
+
     def sessions_per_sec(
         self,
         n: int,

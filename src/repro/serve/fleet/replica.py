@@ -351,6 +351,7 @@ class ProcessReplica:
         **engine_kw,
     ):
         validate_supervision(rpc_timeout_s, rpc_retries, rpc_backoff_s)
+        refuse_shared_chip()
         self.rpc_timeout_s = rpc_timeout_s
         self.rpc_retries = rpc_retries
         self.rpc_backoff_s = rpc_backoff_s
@@ -539,6 +540,24 @@ class ProcessReplica:
         except OSError:
             pass
         self.health = HEALTH_DEAD
+
+
+def refuse_shared_chip() -> None:
+    """Refuse a process replica where this process holds a TPU.
+
+    A chip belongs to one process at a time: once this process has a TPU
+    backend, a spawned replica cannot load the TPU runtime and fails or
+    hangs in its handshake. Fail here instead, before spawning.
+    """
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise ValueError(
+            "process replicas cannot run on this host: this process holds "
+            f"the TPU chip(s) ({jax.device_count()} device(s)) and a chip "
+            "admits one process. Serve with transport='local' (replicas "
+            "share the chip inside this process)."
+        )
 
 
 def start_fleet(

@@ -95,7 +95,7 @@ from repro.api import (
     spec_structural_hash,
 )
 from repro.api.cache import _params_equal
-from repro.core.constants import STOParams
+from repro.core.constants import EXACT_MATMUL, STOParams
 from repro.core.reservoir import Readout, Reservoir, coerce_input_series
 from repro.serve.scheduler import AutoscalePolicy, QueueDepthPolicy, SlotScheduler
 from repro.serve.state_store import SlotStore
@@ -313,7 +313,7 @@ def _apply_readouts(states_plane, w_out):
     xb = jnp.concatenate(
         [states_plane, jnp.ones((1, e), states_plane.dtype)], axis=0
     )
-    return jnp.einsum("ne,eno->eo", xb, w_out)
+    return jnp.einsum("ne,eno->eo", xb, w_out, precision=EXACT_MATMUL)
 
 
 def _apply_readouts_chunk(states_block, w_out):

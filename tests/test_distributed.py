@@ -23,11 +23,12 @@ from repro.configs import get_config, reduce_config
 from repro.distributed import sharding as shd
 from repro.distributed.collectives import dp_mean_grads_compressed
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.train import checkpoint as ckpt
 
 out = {}
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 # --- 1. sharding rules: divisible dims shard, indivisible replicate -------
 cfg = reduce_config(get_config("qwen2-moe-a2.7b"))   # moe: experts=8 % 4 == 0
@@ -53,7 +54,7 @@ out["moe_spec"] = str(spec_of("mlp", "w_gate"))
 # --- 2. compressed psum == plain mean within int8 tolerance ----------------
 grads = {"a": jnp.asarray(np.random.default_rng(0).standard_normal((8, 16)), jnp.float32),
          "b": jnp.asarray(np.random.default_rng(1).standard_normal((4,)), jnp.float32)}
-dp_mesh = jax.make_mesh((8,), ("data",))
+dp_mesh = make_mesh((8,), ("data",))
 red = dp_mean_grads_compressed(dp_mesh, grads, axis_name="data")
 # all shards identical here (replicated input) -> mean == value
 err = max(float(jnp.max(jnp.abs(red[k] - grads[k]))) for k in grads)
@@ -64,7 +65,7 @@ params = m.init(jax.random.PRNGKey(0))
 train_step, opt, _ = steps_mod.make_train_step(cfg)
 opt_state = opt.init(params)
 ckpt.save_checkpoint("/tmp/elastic_ckpt", 3, params, opt_state)
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = make_mesh((4, 2), ("data", "model"))
 p_t = jax.eval_shape(m.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
 o_t = jax.eval_shape(opt.init, p_t)
 p_sh2 = shd.param_shardings(mesh2, p_t)

@@ -427,3 +427,28 @@ def test_process_transport_end_to_end(transport):
         np.testing.assert_array_equal(res.final_m, control.final_m)
     finally:
         rep.close()
+
+
+def test_process_replicas_refused_where_this_process_holds_a_tpu(monkeypatch):
+    """A spawned replica cannot reach a chip this process holds: refused up
+    front, before any child is started, instead of hanging its handshake."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spawned = []
+    monkeypatch.setattr(
+        "multiprocessing.context.SpawnProcess.start",
+        lambda self: spawned.append(self),
+    )
+    with pytest.raises(ValueError, match="holds the TPU"):
+        start_fleet(2, "process", **ENGINE_KW)
+    assert spawned == []
+
+
+def test_planner_from_another_platform_disables_pricing(capsys):
+    """A CPU calibration prices nothing on a TPU: the check prints why and
+    hands back no model."""
+    m = CapacityModel.from_bench(_synthetic_bench(TestPlanner.COEF))
+    assert m.checked_for("cpu") is m
+    assert m.checked_for("tpu") is None
+    assert "admission pricing disabled" in capsys.readouterr().out

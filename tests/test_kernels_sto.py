@@ -117,11 +117,25 @@ class TestTiledKernel:
 
 
 class TestDispatch:
-    def test_auto_picks_fused_small(self):
-        assert ops.fused_fits_vmem(512, 128)
+    """choose_impl on a TPU follows the VMEM fit check (kernel compiles
+    for the chip itself are in tests/test_tpu_compile.py)."""
 
-    def test_auto_picks_tiled_large(self):
-        assert not ops.fused_fits_vmem(4096, 128)
+    def test_auto_picks_fused_small(self, monkeypatch):
+        monkeypatch.setattr(ops, "kernel_vmem_refusal", lambda *a, **k: None)
+        assert ops.choose_impl(512, 128, platform="tpu") == "fused"
+
+    def test_auto_picks_tiled_large(self, monkeypatch):
+        def refuse_fused(impl, *a, **k):
+            return "vmem" if impl == "fused" else None
+
+        monkeypatch.setattr(ops, "kernel_vmem_refusal", refuse_fused)
+        assert ops.choose_impl(4096, 128, platform="tpu") == "tiled"
+        # a measured winner the fit check refuses is passed over too
+        ops.register_impl_choice(4096, 128, "fused", platform="tpu")
+        try:
+            assert ops.choose_impl(4096, 128, platform="tpu") == "tiled"
+        finally:
+            ops._LATENCY_TABLE.clear()
 
     def test_param_sweep_inside_kernel(self):
         """Per-lane parameters: three currents -> three distinct dynamics."""

@@ -390,3 +390,33 @@ def test_tune_compiles_each_structural_combo_once():
     assert [t.fitness for t in first.trials] == [
         t.fitness for t in second.trials
     ], "cached engines changed the search's numerics"
+
+
+class TestCompileCacheDirectory:
+    """api/cache.resolve_cache_dir: the environment's directory wins, else
+    the requested one, else a fixed path inside the checkout."""
+
+    def test_environment_wins_and_warns_once(self, monkeypatch):
+        from repro.api import cache as cache_mod
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/srv/jax-cache")
+        monkeypatch.setattr(cache_mod, "_WARNED_OVERRIDE", False)
+        assert cache_mod.resolve_cache_dir() == "/srv/jax-cache"
+        assert cache_mod.resolve_cache_dir("/srv/jax-cache") == "/srv/jax-cache"
+        with pytest.warns(RuntimeWarning, match="ignoring"):
+            assert cache_mod.resolve_cache_dir("/elsewhere") == "/srv/jax-cache"
+        import warnings
+
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            assert cache_mod.resolve_cache_dir("/elsewhere") == "/srv/jax-cache"
+
+    def test_default_is_fixed_inside_the_checkout(self, monkeypatch):
+        import os
+
+        from repro.api import cache as cache_mod
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cache_mod.resolve_cache_dir() == os.path.join(root, ".jax_cache")
+        assert cache_mod.resolve_cache_dir("/data/c") == "/data/c"

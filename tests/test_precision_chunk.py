@@ -323,6 +323,43 @@ class TestMeasureAndDispatch:
         finally:
             ops._LATENCY_TABLE.clear()
 
+    def test_measure_on_tpu_excludes_refused_and_raises_on_admitted_failure(
+        self, monkeypatch
+    ):
+        """On a TPU a kernel the fit check refuses is left out up front,
+        and one it admitted that then fails raises instead of quietly
+        handing the table to whatever survived."""
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(
+            ops, "kernel_vmem_refusal",
+            lambda impl, *a, **k: "vmem" if impl == "tiled" else None,
+        )
+        try:
+            with pytest.raises(RuntimeError, match="passed the VMEM fit check"):
+                # fused is admitted, then cannot compile: no TPU behind it
+                ops.measure_impl_latency(
+                    8, 4, n_steps=2, reps=1, candidates=("tiled", "fused")
+                )
+            t = ops.measure_impl_latency(
+                8, 4, n_steps=2, reps=1, candidates=("ref", "tiled"),
+                register=False,
+            )
+            assert isinstance(t["ref"], float)
+            assert t["excluded"] == {"tiled": "vmem"}
+        finally:
+            ops._LATENCY_TABLE.clear()
+
+    def test_compile_plan_refuses_a_kernel_that_does_not_fit(self, monkeypatch):
+        monkeypatch.setattr(
+            ops, "kernel_vmem_refusal", lambda *a, **k: "Ran out of vmem"
+        )
+        spec = make_spec(n=8, n_in=1, hold_steps=2, dtype=jnp.float32)
+        for impl in ("fused", "tiled", "chunk"):
+            with pytest.raises(ValueError, match="scoped VMEM limit"):
+                compile_plan(spec, ExecPlan(impl=impl, ensemble=4))
+        # interpret mode compiles no Mosaic kernel: nothing to refuse
+        compile_plan(spec, ExecPlan(impl="fused", ensemble=4, interpret=True))
+
     def test_precision_keyed_choice_with_fallback(self):
         try:
             ops.register_impl_choice(64, 8, "tiled", platform="faux")
