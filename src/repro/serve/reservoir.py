@@ -102,6 +102,11 @@ from repro.serve.state_store import SlotStore
 
 BACKENDS = ("auto", "scan", "ref", "fused", "tiled", "chunk")
 
+# Host spans on the profiler's clock, one per phase of a chunk boundary
+# (`step_chunk`): a trace then puts each device idle gap down to the phase
+# the host was in. With no profiler running, each costs about a microsecond.
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class StreamSession:
@@ -1273,25 +1278,32 @@ class ReservoirEngine:
         Returns None when nothing is left to serve. Runs while the device
         executes the previously launched chunk — this is the overlap the
         pipeline exists for."""
-        # 1) sessions that finished inside the launched chunk: their lanes
-        # were masked off after their last tick, so the chunk-output column
-        # IS their final state — snapshot + free in one gather/scatter pair.
-        self._retire_finishers()
+        with _span("engine.retire"):
+            # 1) sessions that finished inside the launched chunk: their lanes
+            # were masked off after their last tick, so the chunk-output column
+            # IS their final state — snapshot + free in one gather/scatter pair.
+            self._retire_finishers()
 
-        # 1b) lanes the nan guard flagged at the last harvest: force-retire
-        # them (error result) before admissions so their slots refill
-        self._retire_quarantined()
+            # 1b) lanes the nan guard flagged at the last harvest: force-retire
+            # them (error result) before admissions so their slots refill
+            self._retire_quarantined()
 
         # 2) resize at the boundary (slots now reflect retirements)
         if self.autoscale is not None:
             self._maybe_autoscale()
 
         # 3) refill freed slots
-        self._admit_pending()
+        with _span("engine.admit"):
+            self._admit_pending()
         running = self.scheduler.running
         if not running:
             return None
+        with _span("engine.assemble"):
+            return self._assemble_block(running)
 
+    def _assemble_block(self, running) -> Optional[_ChunkPlan]:
+        """Step 4 of `_assemble_chunk`: the chunk's blocks for the running
+        lanes, or None when every resident is an idle open stream."""
         # 4) K-tick input block + per-tick lane masks (mid-chunk retires
         # mask a lane's trailing rows off; the slot refills next boundary),
         # plus — on learning engines — the target block and learn mask
@@ -1348,44 +1360,45 @@ class ReservoirEngine:
 
     def _launch_chunk(self, plan: _ChunkPlan) -> None:
         """Dispatch the chunk; returns immediately (JAX async dispatch)."""
-        store = self.store
-        if self._mask_np is None or not (
-            self._mask_np.shape == plan.mask.shape
-            and np.array_equal(self._mask_np, plan.mask)
-        ):
-            self._mask_np = plan.mask
-            self._mask_dev = jnp.asarray(plan.mask)
-        if self.learn is not None:
-            if self._lmask_np is None or not (
-                self._lmask_np.shape == plan.lmask.shape
-                and np.array_equal(self._lmask_np, plan.lmask)
+        with _span("engine.launch"):
+            store = self.store
+            if self._mask_np is None or not (
+                self._mask_np.shape == plan.mask.shape
+                and np.array_equal(self._mask_np, plan.mask)
             ):
-                self._lmask_np = plan.lmask
-                self._lmask_dev = jnp.asarray(plan.lmask)
-            # one dispatch advances physics AND learning: P/Wl lanes ride
-            # the chunk, a-priori predictions come back in the same result
-            store.m, states_block, (store.P, store.Wl), preds = (
-                self.sim.tick_chunk(
+                self._mask_np = plan.mask
+                self._mask_dev = jnp.asarray(plan.mask)
+            if self.learn is not None:
+                if self._lmask_np is None or not (
+                    self._lmask_np.shape == plan.lmask.shape
+                    and np.array_equal(self._lmask_np, plan.lmask)
+                ):
+                    self._lmask_np = plan.lmask
+                    self._lmask_dev = jnp.asarray(plan.lmask)
+                # one dispatch advances physics AND learning: P/Wl lanes ride
+                # the chunk, a-priori predictions come back in the same result
+                store.m, states_block, (store.P, store.Wl), preds = (
+                    self.sim.tick_chunk(
+                        store.m,
+                        jnp.asarray(plan.u),
+                        lane_mask=self._mask_dev,
+                        params=store.params_ensemble,
+                        targets=jnp.asarray(plan.targets),
+                        learn_state=(store.P, store.Wl),
+                        learn_mask=self._lmask_dev,
+                    )
+                )
+                plan.preds_block = preds
+            else:
+                store.m, states_block = self.sim.tick_chunk(
                     store.m,
                     jnp.asarray(plan.u),
                     lane_mask=self._mask_dev,
                     params=store.params_ensemble,
-                    targets=jnp.asarray(plan.targets),
-                    learn_state=(store.P, store.Wl),
-                    learn_mask=self._lmask_dev,
                 )
-            )
-            plan.preds_block = preds
-        else:
-            store.m, states_block = self.sim.tick_chunk(
-                store.m,
-                jnp.asarray(plan.u),
-                lane_mask=self._mask_dev,
-                params=store.params_ensemble,
-            )
-        plan.states_block = states_block
-        if plan.any_readout:
-            plan.outs_block = _apply_readouts_chunk(states_block, store.w_out)
+            plan.states_block = states_block
+            if plan.any_readout:
+                plan.outs_block = _apply_readouts_chunk(states_block, store.w_out)
 
     def _harvest_chunk(self, plan: _ChunkPlan) -> None:
         """ONE bulk device->host transfer for the chunk, then host-side
@@ -1394,65 +1407,88 @@ class ReservoirEngine:
         When nobody in the chunk collects states, the (K, N, E) block never
         leaves the device (at N=1024, E=256, K=8 that is an 8 MB transfer
         per chunk saved)."""
-        states_np = (
-            np.asarray(plan.states_block)  # (K, N, E)
-            if any(sess.collect_states for sess, _, _ in plan.entries)
-            else None
-        )
-        outs_np = (
-            np.asarray(plan.outs_block) if plan.outs_block is not None else None
-        )
-        preds_np = (
-            np.asarray(plan.preds_block)  # (K, E, n_out)
-            if plan.any_learn and plan.preds_block is not None
-            else None
-        )
-        if self.nan_guard:
-            self._scan_for_nonfinite(plan, states_np, outs_np, preds_np)
-        # .copy(): a bare slice is a VIEW pinning the whole (K, N, E) block
-        # for the session's lifetime — a long-running collector would retain
-        # every chunk block it ever touched instead of its own lane.
-        # Columns beyond the session's own n_out are padding lanes — sliced
-        # off here so accumulators stay at session width.
-        for sess, slot, n in plan.entries:
-            if n == 0:  # idle open stream — nothing served this chunk
-                continue
-            if sess._error is not None:
-                # quarantined: keep the clean prefix, drop the poisoned rows
-                continue
-            if sess.collect_states:
-                sess._states.append(states_np[:n, :, slot].copy())  # (n, N)
-            if sess.readout is not None:
-                sess._outs.append(outs_np[:n, slot, : sess._n_out].copy())
-            if preds_np is not None and sess.targets is not None:
-                sess._preds.append(preds_np[:n, slot, : sess._n_out].copy())
+        with _span("engine.harvest"):
+            with _span("engine.fetch"):
+                # the host waits here for the chunk; the finals of the
+                # sessions retired at the last boundary were gathered from
+                # the same chunk, so they come down in the same wait
+                states_np = (
+                    np.asarray(plan.states_block)  # (K, N, E)
+                    if any(sess.collect_states for sess, _, _ in plan.entries)
+                    else None
+                )
+                outs_np = (
+                    np.asarray(plan.outs_block) if plan.outs_block is not None else None
+                )
+                preds_np = (
+                    np.asarray(plan.preds_block)  # (K, E, n_out)
+                    if plan.any_learn and plan.preds_block is not None
+                    else None
+                )
+                finals = self._fetch_awaiting()
+            if self.nan_guard:
+                with _span("engine.nan_guard"):
+                    self._scan_for_nonfinite(plan, states_np, outs_np, preds_np)
+            # .copy(): a bare slice is a VIEW pinning the whole (K, N, E) block
+            # for the session's lifetime — a long-running collector would retain
+            # every chunk block it ever touched instead of its own lane.
+            # Columns beyond the session's own n_out are padding lanes — sliced
+            # off here so accumulators stay at session width.
+            for sess, slot, n in plan.entries:
+                if n == 0:  # idle open stream — nothing served this chunk
+                    continue
+                if sess._error is not None:
+                    # quarantined: keep the clean prefix, drop the poisoned rows
+                    continue
+                if sess.collect_states:
+                    sess._states.append(states_np[:n, :, slot].copy())  # (n, N)
+                if sess.readout is not None:
+                    sess._outs.append(outs_np[:n, slot, : sess._n_out].copy())
+                if preds_np is not None and sess.targets is not None:
+                    sess._preds.append(preds_np[:n, slot, : sess._n_out].copy())
         # sessions retired at the last boundary: their final chunk is now
         # harvested, so their results are complete
-        self._finalize_awaiting()
+        self._finalize_awaiting(finals)
 
-    def _finalize_awaiting(self) -> None:
+    def _fetch_awaiting(self) -> Optional[Tuple[np.ndarray, Optional[np.ndarray]]]:
+        """Host copies of the final states (and trained weights) of the
+        sessions retired at the previous boundary; None with none."""
+        if self._awaiting is None:
+            return None
+        _, finals, w_finals = self._awaiting
+        return (
+            np.asarray(finals),  # (k, N, 3)
+            np.asarray(w_finals) if w_finals is not None else None,
+        )
+
+    def _finalize_awaiting(
+        self, fetched: Optional[Tuple[np.ndarray, Optional[np.ndarray]]] = None
+    ) -> None:
         """Record results for sessions retired at the previous boundary
         (their final states/weights arrive as one bulk transfer, handed out
-        as copied rows). Safe to call with nothing awaiting."""
-        if self._awaiting is None:
-            return
-        finishers, finals, w_finals = self._awaiting
-        finals_np = np.asarray(finals)  # (k, N, 3)
-        w_np = np.asarray(w_finals) if w_finals is not None else None
-        for i, (slot, sess) in enumerate(finishers):
-            # .copy(): a row view would pin the whole boundary's finals
-            # block per retained result
-            self._record_result(
-                sess,
-                slot,
-                finals_np[i].copy(),
-                learned_w=(
-                    w_np[i].copy()
-                    if w_np is not None and sess.targets is not None
-                    else None
-                ),
-            )
-        self._awaiting = None
+        as copied rows). `fetched` is `_fetch_awaiting()`'s result where the
+        caller already holds it. Safe to call with nothing awaiting."""
+        with _span("engine.finalize"):
+            if self._awaiting is None:
+                return
+            if fetched is None:
+                with _span("engine.fetch"):
+                    fetched = self._fetch_awaiting()
+            finals_np, w_np = fetched
+            for i, (slot, sess) in enumerate(self._awaiting[0]):
+                # .copy(): a row view would pin the whole boundary's finals
+                # block per retained result
+                self._record_result(
+                    sess,
+                    slot,
+                    finals_np[i].copy(),
+                    learned_w=(
+                        w_np[i].copy()
+                        if w_np is not None and sess.targets is not None
+                        else None
+                    ),
+                )
+            self._awaiting = None
 
     def step_chunk(self) -> bool:
         """Advance the pipeline by one chunk. Returns False when drained.
@@ -1465,31 +1501,32 @@ class ReservoirEngine:
         control back to `run()` — so no launched chunk is left unharvested;
         don't interleave with per-tick `step()` while a chunk is in flight.
         """
-        t0 = time.perf_counter()
-        plan = self._assemble_chunk()
-        if plan is not None:
-            self._launch_chunk(plan)
-        if self._pending is not None:
-            self._harvest_chunk(self._pending)
-        else:
-            # nothing in flight, but the boundary may still have snapshot
-            # finals to hand out (all-idle open streams after a finisher)
-            self._finalize_awaiting()
-        self._pending = plan
-        if plan is not None:
-            self._chunk_times.append(time.perf_counter() - t0)
-        progress = plan is not None
-        # advance mixed-spec tenants in lockstep; their finished sessions
-        # surface through OUR results map so callers have one drain point
-        for sub in self._subengines.values():
-            if sub.step_chunk():
-                progress = True
-            if sub.results:
-                self.results.update(sub.pop_results())
-        if self._subengines and self.max_retained is not None:
-            while len(self.results) > self.max_retained:
-                self.results.pop(next(iter(self.results)))
-        return progress
+        with _span("engine.step_chunk"):
+            t0 = time.perf_counter()
+            plan = self._assemble_chunk()
+            if plan is not None:
+                self._launch_chunk(plan)
+            if self._pending is not None:
+                self._harvest_chunk(self._pending)
+            else:
+                # nothing in flight, but the boundary may still have snapshot
+                # finals to hand out (all-idle open streams after a finisher)
+                self._finalize_awaiting()
+            self._pending = plan
+            if plan is not None:
+                self._chunk_times.append(time.perf_counter() - t0)
+            progress = plan is not None
+            # advance mixed-spec tenants in lockstep; their finished sessions
+            # surface through OUR results map so callers have one drain point
+            for sub in self._subengines.values():
+                if sub.step_chunk():
+                    progress = True
+                if sub.results:
+                    self.results.update(sub.pop_results())
+            if self._subengines and self.max_retained is not None:
+                while len(self.results) > self.max_retained:
+                    self.results.pop(next(iter(self.results)))
+            return progress
 
     def run(
         self, sessions: Optional[List[StreamSession]] = None
