@@ -282,6 +282,9 @@ class EngineStats:
     # engine). Defaults keep older pickled stats loadable.
     quarantined_lanes: int = 0
     health: str = "healthy"
+    # chunks whose launch returned before the launched chunk's states were
+    # ready: the host got on with the boundary while the kernel ran
+    launches_overlapped: int = 0
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -321,17 +324,19 @@ def _apply_readouts(states_plane, w_out):
     return jnp.einsum("ne,eno->eo", xb, w_out, precision=EXACT_MATMUL)
 
 
+@jax.jit
 def _apply_readouts_chunk(states_block, w_out):
     """Chunked readout: (K, N, E) x (E, N+1, n_out) -> (K, E, n_out).
 
-    K dispatches of the SAME compiled `_apply_readouts` the per-tick path
-    uses, stacked on device — a single batched einsum ("kne,eno->keo")
-    contracts in a different order and drifts from the per-tick outputs by
-    a ULP, and chunked serving pins bit-equality with per-tick serving.
-    The stack stays device-side until the once-per-chunk harvest."""
-    return jnp.stack(
-        [_apply_readouts(states_block[t], w_out) for t in range(states_block.shape[0])]
-    )
+    ONE device program that maps the per-tick `_apply_readouts` over the K
+    planes — a single batched einsum ("kne,eno->keo") contracts in a
+    different order and drifts from the per-tick outputs by a ULP, and
+    chunked serving pins bit-equality with per-tick serving. It must stay
+    one dispatch at any K: the launch queues it behind the running kernel,
+    and the runtime holds the host once about 32 programs are in flight
+    on a device, so K eager slices and readouts would make the launch wait
+    out the kernel. The result stays device-side until the harvest."""
+    return jax.lax.map(lambda plane: _apply_readouts(plane, w_out), states_block)
 
 
 def _spec_host(spec: Optional[SimSpec]) -> Optional[SimSpec]:
@@ -617,6 +622,7 @@ class ReservoirEngine:
         # wall time of recent step_chunk calls that launched work — the
         # stats() latency signal the fleet planner checks itself against
         self._chunk_times: deque = deque(maxlen=128)
+        self._launches_overlapped = 0
         # -- spec-level multi-tenancy ---------------------------------------
         # sessions whose SimSpec structural hash differs from the template's
         # serve on an internal sub-engine compiled for THEIR spec (one per
@@ -1399,6 +1405,8 @@ class ReservoirEngine:
             plan.states_block = states_block
             if plan.any_readout:
                 plan.outs_block = _apply_readouts_chunk(states_block, store.w_out)
+            if not states_block.is_ready():  # non-blocking
+                self._launches_overlapped += 1
 
     def _harvest_chunk(self, plan: _ChunkPlan) -> None:
         """ONE bulk device->host transfer for the chunk, then host-side
@@ -1824,6 +1832,7 @@ class ReservoirEngine:
             rescale_stall_s=sched.stats.rescale_stall_s,
             chunk_median_s=median,
             chunks_timed=len(timed),
+            launches_overlapped=self._launches_overlapped,
             ticks_per_sec=(
                 None
                 if not median

@@ -13,19 +13,30 @@ The contracts this file pins:
   - Autoscaling migrates running sessions between bucketed plans without
     perturbing their dynamics; scheduler stats expose the load signals.
   - `pop_results` / `max_retained` bound retired-session retention.
+  - The chunk readout is ONE jitted program, bit-exact against K per-tick
+    readouts, so `_launch_chunk` returns while the chunk still runs
+    (`EngineStats.launches_overlapped`).
   - ExecPlan rejects chunk_ticks < 1 / non-int and non-dtype gather_dtype.
 """
 
 import dataclasses
+import pickle
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.api import ExecPlan, compile_plan, make_spec
-from repro.core import drive, fit_ridge, make_reservoir
+from repro.core import Readout, drive, fit_ridge, make_reservoir
 from repro.kernels import ops
-from repro.serve.reservoir import ReservoirEngine, StreamSession, _bucket_slots
+from repro.serve.reservoir import (
+    EngineStats,
+    ReservoirEngine,
+    StreamSession,
+    _apply_readouts,
+    _apply_readouts_chunk,
+    _bucket_slots,
+)
 from repro.serve.scheduler import AutoscalePolicy, QueueDepthPolicy, SlotScheduler
 
 ATOL = 5e-5  # tests/test_kernels_sto.py's f32 tolerance
@@ -349,3 +360,60 @@ class TestPlanValidation:
     def test_plan_replace_keeps_chunk_ticks(self):
         plan = ExecPlan(ensemble=4, chunk_ticks=8)
         assert dataclasses.replace(plan, ensemble=16).chunk_ticks == 8
+
+
+class TestChunkReadoutProgram:
+    @pytest.mark.parametrize(
+        "k,n,e,n_out", [(8, 1000, 256, 1), (8, 1, 4096, 1), (4, 12, 3, 1), (8, 64, 32, 3)]
+    )
+    def test_one_program_bitexact_vs_per_tick(self, k, n, e, n_out):
+        """The chunk readout is one jitted program (one dispatch at any K)
+        and the same numbers, bit for bit, as K per-tick readouts."""
+        rng = np.random.default_rng(k * n + e)
+        states = jnp.asarray(rng.standard_normal((k, n, e)).astype(np.float32))
+        w_out = jnp.asarray(rng.standard_normal((e, n + 1, n_out)).astype(np.float32))
+        assert hasattr(_apply_readouts_chunk, "lower")
+        got = _apply_readouts_chunk(states, w_out)
+        want = jnp.stack([_apply_readouts(states[t], w_out) for t in range(k)])
+        assert got.shape == (k, e, n_out)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_launch_returns_before_chunk_ends(self):
+        """Every chunk launched after warm-up is still running when
+        `_launch_chunk` returns (a chunk here takes ~0.1 s on a CPU; a
+        slower host only makes that more certain)."""
+        res = make_reservoir(n=128, n_in=1, hold_steps=100, dtype=jnp.float32)
+        eng = ReservoirEngine(res, num_slots=32, backend="ref", chunk_ticks=8)
+        rng = np.random.default_rng(0)
+        ro = Readout(
+            w_out=jnp.asarray(0.01 * rng.standard_normal((129, 1)).astype(np.float32)),
+            washout=0,
+        )
+
+        def wave(base):
+            for i in range(32):
+                u = rng.uniform(0.0, 0.5, (16, 1)).astype(np.float32)
+                eng.submit(StreamSession(sid=base + i, u_seq=u, readout=ro))
+
+        wave(0)
+        while eng.step_chunk():  # warm-up: compiles every program of a wave
+            pass
+        before = eng.stats()
+        wave(100)
+        while eng.step_chunk():
+            pass
+        after = eng.stats()
+        launched = after.chunks_timed - before.chunks_timed
+        assert launched == 2
+        assert after.launches_overlapped - before.launches_overlapped == launched
+        assert len(eng.results) == 64
+
+    def test_stats_without_counter_still_load(self):
+        res = make_reservoir(n=6, n_in=1, hold_steps=4, dtype=jnp.float32)
+        stats = ReservoirEngine(res, num_slots=2, backend="scan").stats()
+        assert stats.launches_overlapped == 0
+        old = stats.to_dict()
+        del old["launches_overlapped"]
+        assert EngineStats(**old).launches_overlapped == 0
+        del stats.__dict__["launches_overlapped"]  # as pickled before the field
+        assert pickle.loads(pickle.dumps(stats)).launches_overlapped == 0
