@@ -15,7 +15,7 @@ import math
 
 import numpy as np
 
-STREAM_W, STREAM_W_IN, STREAM_READOUT, STREAM_SAMPLE = 0, 1, 2, 4
+STREAM_W, STREAM_W_IN, STREAM_READOUT, STREAM_SAMPLE, STREAM_AUDIT = 0, 1, 2, 4, 5
 _MASK64 = np.uint64(0xFFFFFFFFFFFFFFFF)
 
 

@@ -17,6 +17,13 @@ back in the window, drawn from the seed, from their start; and every lane
 over AUDIT_CHUNKS more chunks of the same traffic, each restarted from
 the state the engine served (`ReservoirEngine.snapshot_sessions`) and
 compared one chunk ahead.
+
+A deployment that learns its readout online (plan.learn "rls", a mix with
+targets) is checked on what it serves: its a-priori predictions take the
+place of the outputs, and the chunk audit restarts the reference's learner
+from a seeded sample of lanes' served (m, P, W): `audit_learn_lanes` in the
+cell file, since one P is (N+1)^2 floats. Which gaps a configuration
+computes is GAPS[learning]; a limit on any other is refused before the run.
 """
 
 from __future__ import annotations
@@ -37,6 +44,15 @@ from benchlib import registry
 
 AUDIT_CHUNKS = 2  # chunks served after the window and compared one chunk ahead
 PROFILE_S = 5.0  # the window's throughput is also noted per this many seconds
+
+# the numbers compared with the reference, for a readout deployment (False)
+# and for one that learns its readout online (True)
+GAPS = {
+    False: ("outputs_gap", "outputs_gap_median", "chunk_states_gap",
+            "chunk_outputs_gap"),
+    True: ("outputs_gap", "outputs_gap_median", "chunk_states_gap",
+           "chunk_weights_gap", "chunk_preds_gap"),
+}
 
 _COMPILE_EVENTS = {
     "/jax/core/compile/jaxpr_trace_duration": "traced",
@@ -98,6 +114,19 @@ class Record:
 
 
 @dataclasses.dataclass
+class Lane:
+    """A live session at an audit snapshot: its tick, its state m (N, 3),
+    its outputs so far, or where it learns its predictions so far, its
+    learned W and, on a sampled lane only, its P."""
+    t: int
+    m: np.ndarray
+    outs: Optional[np.ndarray] = None
+    preds: Optional[np.ndarray] = None
+    wl: Optional[np.ndarray] = None
+    p: Optional[np.ndarray] = None
+
+
+@dataclasses.dataclass
 class Result:
     line: dict
     checks: List[Tuple[str, float, float]]
@@ -108,14 +137,43 @@ class Result:
 
 
 def apply_rehearsal(cell: registry.Cell) -> None:
-    """Shrink a cell to the CPU rehearsal's sizes (bench/rehearsal.json)."""
+    """Shrink a cell to the CPU rehearsal's sizes (bench/rehearsal.json).
+    A key of the mix or of the cell file is shrunk only where the cell
+    states it, so a readout cell gains no learning key."""
     r = registry.rehearsal()
     spec = cell.config["spec"]
     spec.update(r["spec"], n=min(int(spec["n"]), int(r["spec"]["n"])))
     cell.config["plan"].update(r["plan"])
     cell.config["readout"].update(r["readout"])
-    cell.traffic.update(r["traffic"].get(cell.traffic["kind"], {}))
-    cell.check.update(r["check"])
+    cell.traffic.update({k: v for k, v in r["traffic"].get(cell.traffic["kind"], {}).items()
+                         if k in cell.traffic})
+    cell.check.update({k: v for k, v in r["check"].items() if k in cell.check})
+
+
+def validate(cell: registry.Cell) -> bool:
+    """Refuse, before any run, a cell that its deployment cannot run as
+    stated or whose limits name a gap the deployment does not compute.
+    Returns whether the deployment learns."""
+    from benchlib import system
+
+    mix = traffic.validate(dict(cell.traffic))
+    system.validate_deployment(cell.config, mix, cell.chips)
+    learning = "targets" in mix
+    unknown = sorted(set(cell.check["limits"]) - set(GAPS[learning]))
+    if unknown:
+        raise ValueError(f"cell {cell.name}: limits on {unknown}, which a "
+                         f"{'learning' if learning else 'readout'} deployment does "
+                         f"not compute; it computes {list(GAPS[learning])}")
+    if learning:
+        if int(cell.check.get("audit_learn_lanes", 0)) < 1:
+            raise ValueError(f"cell {cell.name}: a learning cell states "
+                             "audit_learn_lanes >= 1")
+        if int(cell.check["horizon_ticks"]) <= traffic.washout(mix):
+            raise ValueError(f"cell {cell.name}: horizon_ticks "
+                             f"{cell.check['horizon_ticks']} does not pass "
+                             f"learn_washout {traffic.washout(mix)}, so no "
+                             "learned prediction would be compared")
+    return learning
 
 
 class Run:
@@ -130,7 +188,9 @@ class Run:
         self.rehearse = bool(rehearse)
         self.t_process = t_process
         self.cfg = cell.config
-        self.mix = traffic.validate(dict(cell.traffic))
+        self.learning = validate(cell)
+        self.mix = dict(cell.traffic)
+        self.washout = traffic.washout(self.mix)
         self.e = int(self.cfg["plan"]["ensemble"])
         self.k = int(self.cfg["plan"]["chunk_ticks"])
         self.n_in = int(self.cfg["spec"]["n_in"])
@@ -146,9 +206,15 @@ class Run:
 
     def _submit(self, arrival: traffic.Arrival):
         u = traffic.inputs(self.mix, self.seed, arrival, self.n_in)
-        pool = self.data["readouts"]
-        readout = pool[traffic.readout_index(arrival, pool.shape[0])]
-        self.eng.submit(self.sys.session(arrival.sid, u, readout))
+        if self.learning:
+            session = self.sys.session(arrival.sid, u, None,
+                                       targets=traffic.targets(self.mix, u),
+                                       washout=self.washout)
+        else:
+            pool = self.data["readouts"]
+            readout = pool[traffic.readout_index(arrival, pool.shape[0])]
+            session = self.sys.session(arrival.sid, u, readout)
+        self.eng.submit(session)
         self.records[arrival.sid] = Record(arrival)
 
     def _step(self) -> bool:
@@ -196,7 +262,8 @@ class Run:
     def setup(self):
         self.data = data.make(self.cfg, self.seed)
         t0 = time.perf_counter()
-        self.eng = self.sys.engine(self.cfg, self.data, interpret=self.rehearse)
+        self.eng = self.sys.engine(self.cfg, self.data, chips=self.cell.chips,
+                                   interpret=self.rehearse)
         self._warm_shapes()
         self.compile_s = time.perf_counter() - t0
         self._closed_ramp()
@@ -262,18 +329,31 @@ class Run:
         the pipeline drained before each, and every live session's state
         read (`snapshot_sessions`), so that the check can restart the
         reference from each lane's served state and compare one chunk
-        ahead. Untimed; the closed loop keeps every slot busy."""
-        def snapshot():
-            snap = {c.sid: (c.t, c.m, c.outs) for c in self.eng.snapshot_sessions()
-                    if c.m is not None}
+        ahead. Untimed; the closed loop keeps every slot busy.
+
+        Where sessions learn, each snapshot before a chunk keeps the P of
+        `audit_learn_lanes` lanes drawn from the seed and drops the rest
+        at once; the last snapshot keeps none."""
+        picker = data.rng(self.seed, data.STREAM_AUDIT)
+
+        def snapshot(keep_p: bool):
+            ckpts = [c for c in self.eng.snapshot_sessions() if c.m is not None]
+            kept = set()
+            if self.learning and keep_p and ckpts:
+                sids = sorted(c.sid for c in ckpts)
+                k = min(int(self.cell.check["audit_learn_lanes"]), len(sids))
+                kept = {sids[i] for i in picker.choice(len(sids), size=k, replace=False)}
+            snap = {c.sid: Lane(c.t, c.m, c.outs, c.preds, c.Wl,
+                                c.P if c.sid in kept else None) for c in ckpts}
+            del ckpts
             self._collect()
             return snap
 
-        after = snapshot()
-        for _ in range(AUDIT_CHUNKS):
+        after = snapshot(True)
+        for i in range(AUDIT_CHUNKS):
             before = after
             self._step()
-            after = snapshot()
+            after = snapshot(i + 1 < AUDIT_CHUNKS)
             self.audit_chunks.append((before, after))
         self._feeding = False
 
@@ -332,6 +412,12 @@ def _ok(res) -> bool:
     return res is not None and getattr(res, "error", None) is None
 
 
+def _learner(run: Run, targets, skip, p0=None, w0=None) -> dict:
+    plan = run.cfg["plan"]
+    return {"lam": float(plan["learn_lam"]), "reg": float(plan["learn_reg"]),
+            "targets": targets, "skip": skip, "p0": p0, "w0": w0}
+
+
 def compare(run: Run, sids: List[int]) -> Dict[str, float]:
     """The sampled sessions from their start, over their first
     `horizon_ticks` ticks (PERF.md, section 2):
@@ -342,25 +428,63 @@ def compare(run: Run, sids: List[int]) -> Dict[str, float]:
                           is short where N is large
       outputs_gap_median  the median session's widest gap: steadier from
                           seed to seed than the widest
+
+    Where sessions learn, the outputs compared are the a-priori
+    predictions, against the reference's learner from P = I / reg, W = 0.
     """
     h = int(run.cell.check["horizon_ticks"])
     arrivals = [run.records[s].arrival for s in sids]
-    ins = [traffic.inputs(run.mix, run.seed, a, run.n_in)[: min(a.ticks, h)]
-           for a in arrivals]
-    ref = reference.drive(run.data, ins, [len(u) for u in ins],
-                          [_readout(run, a) for a in arrivals],
-                          block=int(run.cell.check["reference_block"]))
+    full = [traffic.inputs(run.mix, run.seed, a, run.n_in) for a in arrivals]
+    ins = [u[: min(a.ticks, h)] for u, a in zip(full, arrivals)]
+    block = int(run.cell.check["reference_block"])
+    if run.learning:
+        learner = _learner(run, [traffic.targets(run.mix, u)[: len(x)]
+                                 for u, x in zip(full, ins)],
+                           [run.washout] * len(ins))
+        ref = reference.drive(run.data, ins, [len(u) for u in ins],
+                              block=block, learner=learner)
+        key = "predictions"
+    else:
+        ref = reference.drive(run.data, ins, [len(u) for u in ins],
+                              [_readout(run, a) for a in arrivals], block=block)
+        key = "outputs"
     out = {"outputs_gap": 0.0}
     per_session = []
     for s, a, want in zip(sids, arrivals, ref):
         res = run.records[s].result
-        x = res.outputs if _ok(res) else None
+        x = getattr(res, key) if _ok(res) else None
         ok = x is not None and np.shape(x)[0] == a.ticks
-        gap = _gap(x[: min(a.ticks, h)] if ok else None, want["outputs"])
+        gap = _gap(x[: min(a.ticks, h)] if ok else None, want[key])
         per_session.append(gap)
         out["outputs_gap"] = max(out["outputs_gap"], gap)
     out["outputs_gap_median"] = float(np.median(per_session)) if per_session else 0.0
     return out
+
+
+def _weights_gap(served, want) -> float:
+    """Widest |served - reference| learned weight over the reference's widest
+    weight of that lane (0 where both are all zero)."""
+    if served is None or np.shape(served) != np.shape(want):
+        return math.inf
+    scale = float(np.max(np.abs(want))) if np.size(want) else 0.0
+    gap = _gap(served, want)
+    if gap == 0.0:
+        return 0.0
+    return gap / scale if scale > 0.0 else math.inf
+
+
+def _served_after(run: Run, sid: int, after: Dict[int, Lane]):
+    """(t1, m, outputs, predictions, learned W) of a lane after an audit
+    chunk; a session that finished in the chunk is held to its result."""
+    if sid in after:
+        lane = after[sid]
+        return lane.t, lane.m, lane.outs, lane.preds, lane.wl
+    res = run.records[sid].result
+    t1 = run.records[sid].arrival.ticks
+    if not _ok(res):
+        return t1, None, None, None, None
+    wl = None if res.learned_readout is None else np.asarray(res.learned_readout.w_out)
+    return t1, res.final_m, res.outputs, res.predictions, wl
 
 
 def compare_audit(run: Run) -> Dict[str, float]:
@@ -372,34 +496,67 @@ def compare_audit(run: Run) -> Dict[str, float]:
                           session admitted in the chunk, from its start)
       chunk_outputs_gap   widest output gap over the chunk's ticks
 
+    Where sessions learn, on the lanes whose P was kept before the chunk,
+    the reference's learner restarted from their served (m, P, W):
+
+      chunk_weights_gap   widest learned-weight gap after the chunk, over
+                          the reference's widest weight of the lane
+      chunk_preds_gap     widest prediction gap over the chunk's ticks
+
     A session that finished in the chunk is held to its result."""
-    starts, ins, lens, readouts, served = [], [], [], [], []
+    starts, ins, lens, readouts, served, learn = [], [], [], [], [], []
     for before, after in run.audit_chunks:
         for sid in sorted(set(before) | set(after)):
             a = run.records[sid].arrival
-            t0, m0, _ = before.get(sid, (0, None, None))
-            if sid in after:
-                t1, m1, outs = after[sid]
-            else:
-                res = run.records[sid].result
-                t1 = a.ticks
-                m1, outs = (res.final_m, res.outputs) if _ok(res) else (None, None)
+            lane = before.get(sid)
+            t0, m0 = (lane.t, lane.m) if lane is not None else (0, None)
+            t1, m1, outs, preds, wl = _served_after(run, sid, after)
             if t1 <= t0:
                 continue
+            u = traffic.inputs(run.mix, run.seed, a, run.n_in)
             starts.append(m0)
-            ins.append(traffic.inputs(run.mix, run.seed, a, run.n_in)[t0:t1])
+            ins.append(u[t0:t1])
             lens.append(t1 - t0)
-            readouts.append(_readout(run, a))
-            ok = outs is not None and np.shape(outs)[0] >= t1
-            served.append((m1, outs[t0:t1] if ok else None))
-    out = {"chunk_states_gap": 0.0, "chunk_outputs_gap": 0.0}
-    if not served:
-        return dict.fromkeys(out, math.inf)
-    ref = reference.drive(run.data, ins, lens, readouts, starts=starts,
-                          block=int(run.cell.check["reference_block"]))
+            if run.learning:
+                served.append((m1, None))
+                if lane is not None and lane.p is not None:
+                    ok = preds is not None and np.shape(preds)[0] >= t1
+                    learn.append({
+                        "start": m0, "u": u[t0:t1], "p0": lane.p, "w0": lane.wl,
+                        "targets": traffic.targets(run.mix, u)[t0:t1],
+                        "skip": max(0, run.washout - t0),
+                        "wl": wl, "preds": preds[t0:t1] if ok else None})
+            else:
+                readouts.append(_readout(run, a))
+                ok = outs is not None and np.shape(outs)[0] >= t1
+                served.append((m1, outs[t0:t1] if ok else None))
+    names = GAPS[run.learning][2:]
+    out = dict.fromkeys(names, 0.0)
+    if not served or (run.learning and not learn):
+        return dict.fromkeys(names, math.inf)
+    block = int(run.cell.check["reference_block"])
+    ref = reference.drive(run.data, ins, lens, None if run.learning else readouts,
+                          starts=starts, block=block)
     for (m1, outs), want in zip(served, ref):
         out["chunk_states_gap"] = max(out["chunk_states_gap"], _gap(m1, want["final_m"]))
-        out["chunk_outputs_gap"] = max(out["chunk_outputs_gap"], _gap(outs, want["outputs"]))
+        if not run.learning:
+            out["chunk_outputs_gap"] = max(out["chunk_outputs_gap"],
+                                           _gap(outs, want["outputs"]))
+    del ref
+    if run.learning:
+        ref = reference.drive(
+            run.data, [r["u"] for r in learn], [len(r["u"]) for r in learn],
+            starts=[r["start"] for r in learn], block=block,
+            learner=_learner(run, [r["targets"] for r in learn],
+                             [r["skip"] for r in learn],
+                             [r["p0"] for r in learn], [r["w0"] for r in learn]))
+        for r, want in zip(learn, ref):
+            out["chunk_weights_gap"] = max(out["chunk_weights_gap"],
+                                           _weights_gap(r["wl"], want["final_w"]))
+            out["chunk_preds_gap"] = max(out["chunk_preds_gap"],
+                                         _gap(r["preds"], want["predictions"]))
+        run.notes.append(f"audit: {len(learn)} learning lane-chunks restarted "
+                         "from their served P and W")
     run.notes.append(f"audit: {len(served)} lane-chunks compared one chunk ahead")
     return out
 
@@ -449,6 +606,9 @@ class Context:
             "n_out": int(run.cfg["readout"]["n_out"]),
             "itemsize": 4,
             "collect_states": False,
+            "chips": run.cell.chips,
+            "learn": run.cfg["plan"].get("learn"),
+            "s": int(run.cfg["spec"]["n"]) + 1,
         }
         self.records = run.records
         self.window = (run.t_window, run.t_end)

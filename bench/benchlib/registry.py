@@ -21,8 +21,8 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 
 
-def _json(*parts: str) -> dict:
-    path = os.path.join(BENCH_DIR, *parts)
+def _json(*parts: str, base: str = BENCH_DIR) -> dict:
+    path = os.path.join(base, *parts)
     with open(path) as f:
         return json.load(f)
 
@@ -43,9 +43,12 @@ def benchmark() -> dict:
 
 
 class Cell:
-    """One workload of BENCHMARK.json with everything it names, loaded."""
+    """One workload of BENCHMARK.json with everything it names, loaded.
 
-    def __init__(self, name: str, bench: dict = None):
+    `bench` and `base` stand in for BENCHMARK.json and bench/ where a test
+    keeps cells of its own (bench/testdata/)."""
+
+    def __init__(self, name: str, bench: dict = None, base: str = BENCH_DIR):
         bench = bench or benchmark()
         cells = {w["name"]: w for w in bench["workloads"]}
         if name not in cells:
@@ -53,9 +56,9 @@ class Cell:
         self.name = name
         self.entry = cells[name]
         self.chips = int(self.entry["chips"])
-        self.config = _json("configs", self.entry["config"] + ".json")
-        self.traffic = _json("traffic", self.entry["traffic"] + ".json")
-        self.check = _json("cells", name + ".json")
+        self.config = _json("configs", self.entry["config"] + ".json", base=base)
+        self.traffic = _json("traffic", self.entry["traffic"] + ".json", base=base)
+        self.check = _json("cells", name + ".json", base=base)
         self.end_to_end = [m for m in bench["end_to_end"] if self._has(m)]
         reported = {m["name"] for m in self.end_to_end}
         self.per_layer = [

@@ -58,6 +58,13 @@ def main(argv=None) -> int:
         print(f"bench: the system under test (src/repro) cannot be imported: {exc}",
               file=sys.stderr)
         return 2
+    from benchlib import harness
+
+    try:
+        harness.validate(cell)
+    except ValueError as exc:
+        print(f"bench: {exc}", file=sys.stderr)
+        return 2
 
     import jax
 
@@ -68,21 +75,19 @@ def main(argv=None) -> int:
             print("bench: --rehearse runs on the CPU only (JAX_PLATFORMS=cpu)",
                   file=sys.stderr)
             return 2
-        from benchlib import harness
-
         harness.apply_rehearsal(cell)
     elif platform not in ("tpu", "gpu"):
         print(f"bench: no accelerator (JAX platform {platform!r}); this "
               "benchmark measures a chip (--rehearse is the CPU rehearsal)",
               file=sys.stderr)
         return 2
-    elif len(devices) < cell.chips:
+    if len(devices) < cell.chips:
+        hint = (" (XLA_FLAGS=--xla_force_host_platform_device_count="
+                f"{cell.chips} gives the rehearsal as many)" if args.rehearse else "")
         print(f"bench: {args.workload} needs {cell.chips} chips, JAX finds "
-              f"{len(devices)}", file=sys.stderr)
+              f"{len(devices)}{hint}", file=sys.stderr)
         return 2
     system.enable_persistent_cache()
-    from benchlib import harness
-
     harness.CompileCounter.get()
     res = harness.execute(cell, args.seed, args.seconds, bool(args.trace),
                           args.rehearse, T_PROCESS)
